@@ -20,6 +20,9 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     from benchmarks import (
         fig1_transformer_lr_stability,
         fig3_mlp_lr_stability,

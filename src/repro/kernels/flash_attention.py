@@ -16,7 +16,7 @@ TPU-native design (not a CUDA port):
 
 Backward (Dao et al. 2022 style, recomputation-based):
   - the forward additionally emits the per-row logsumexp ``lse = m + log l``
-    (shape (B, H, S)); softmax probabilities are *recomputed* blockwise in
+    (shape (B, H, S, 1)); softmax probabilities are *recomputed* blockwise in
     the backward kernels as ``p = exp(logits - lse)`` instead of stashing
     the (S, T) matrix — O(S) residual memory instead of O(S^2).
   - dq kernel: grid (B, H, nq, nk) — for each q tile, accumulate
@@ -24,7 +24,7 @@ Backward (Dao et al. 2022 style, recomputation-based):
   - dk/dv kernel: grid (B, K, nk, G, nq) — for each kv tile, accumulate
     ``dv += p^T @ do`` and ``dk += ds^T @ q`` over the (group, q-tile)
     inner dims, summing the G query heads of a GQA group in-kernel so the
-    dk/dv written to HBM are already (B, T, K, d).
+    dk/dv written to HBM are already per kv head.
   - ``delta = rowsum(do * o)`` (the softmax-jacobian correction) is a cheap
     elementwise reduce done in plain jnp between the two kernels.
   - softcap backward: the tanh derivative is computed from the *pre-mask*
@@ -97,9 +97,9 @@ def _flash_kernel(
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)          # (bq, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (bk, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)          # (bk, d)
+        q = q_ref[0, 0].astype(jnp.float32)          # (bq, d)
+        k = k_ref[0, 0].astype(jnp.float32)          # (bk, d)
+        v = v_ref[0, 0].astype(jnp.float32)          # (bk, d)
         s = kernel_dot(q, k.T, policy) * scale
         if softcap:
             s = softcap * jnp.tanh(s / softcap)
@@ -121,9 +121,9 @@ def _flash_kernel(
     def _finalize():
         l = l_ref[...]
         out = acc_ref[...] / jnp.maximum(l, 1e-30)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
         lse = m_ref[...] + jnp.log(jnp.maximum(l, 1e-30))
-        lse_ref[0, 0, :] = lse[:, 0]
+        lse_ref[0, 0] = lse
 
 
 def _recompute_p_ds(
@@ -173,12 +173,12 @@ def _flash_bwd_dq_kernel(
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        lse_row = lse_ref[0, 0, :][:, None]
-        delta_row = delta_ref[0, 0, :][:, None]
+        q = q_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
+        do = do_ref[0, 0].astype(jnp.float32)
+        lse_row = lse_ref[0, 0]
+        delta_row = delta_ref[0, 0]
         _, ds = _recompute_p_ds(
             q, k, v, do, lse_row, delta_row, q_start, k_start,
             scale=scale, causal=causal, window=window, softcap=softcap,
@@ -188,7 +188,7 @@ def _flash_bwd_dq_kernel(
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        dq_ref[0, :, 0, :] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[0, 0] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(
@@ -212,12 +212,12 @@ def _flash_bwd_dkv_kernel(
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        lse_row = lse_ref[0, 0, :][:, None]
-        delta_row = delta_ref[0, 0, :][:, None]
+        q = q_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
+        do = do_ref[0, 0].astype(jnp.float32)
+        lse_row = lse_ref[0, 0]
+        delta_row = delta_ref[0, 0]
         p, ds = _recompute_p_ds(
             q, k, v, do, lse_row, delta_row, q_start, k_start,
             scale=scale, causal=causal, window=window, softcap=softcap,
@@ -228,18 +228,23 @@ def _flash_bwd_dkv_kernel(
 
     @pl.when(jnp.logical_and(gi == n_group - 1, qi == nq - 1))
     def _finalize():
-        dk_ref[0, :, 0, :] = dk_acc_ref[...].astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_acc_ref[...].astype(dv_ref.dtype)
+        dk_ref[0, 0] = dk_acc_ref[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc_ref[...].astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
 # pallas_call wrappers
 # ---------------------------------------------------------------------------
+# The kernels see heads-major arrays — q/o/do (B, H, S, d), k/v (B, K, T, d),
+# lse/delta (B, H, S, 1) — so every block's last two dims are a whole
+# (rows, d) tile, the only shape the TPU compiler accepts for a one-head
+# block.  The (B, S, H, d) <-> heads-major transposes happen outside
+# pallas_call, once per call, in _flash_fn.
 
 def _fwd_call(q, k, v, *, scale, causal, window, softcap, bq, bk, interpret,
               policy=None):
-    B, S, H, d = q.shape
-    T, K = k.shape[1], k.shape[2]
+    B, H, S, d = q.shape
+    K, T = k.shape[1], k.shape[2]
     G = H // K
     nq, nk = S // bq, T // bk
     kernel = functools.partial(
@@ -251,17 +256,17 @@ def _fwd_call(q, k, v, *, scale, causal, window, softcap, bq, bk, interpret,
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, d), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, bk, 1, d), lambda b, h, qi, ki: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, bk, 1, d), lambda b, h, qi, ki: (b, ki, h // G, 0)),
+            pl.BlockSpec((1, 1, bq, d), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda b, h, qi, ki: (b, h // G, ki, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda b, h, qi, ki: (b, h // G, ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, 1, d), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, qi, ki: (b, h, qi)),
+            pl.BlockSpec((1, 1, bq, d), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, qi, ki: (b, h, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, S, H, d), q.dtype),
-            jax.ShapeDtypeStruct((B, H, S), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, S, d), q.dtype),
+            jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),   # acc
@@ -276,8 +281,8 @@ def _bwd_dq_call(
     q, k, v, do, lse, delta, *, scale, causal, window, softcap, bq, bk,
     interpret, policy=None,
 ):
-    B, S, H, d = q.shape
-    T, K = k.shape[1], k.shape[2]
+    B, H, S, d = q.shape
+    K, T = k.shape[1], k.shape[2]
     G = H // K
     nq, nk = S // bq, T // bk
     kernel = functools.partial(
@@ -285,19 +290,17 @@ def _bwd_dq_call(
         scale=scale, causal=causal, window=window, softcap=softcap,
         bq=bq, bk=bk, nk=nk, seq_len=T, policy=policy,
     )
+    q_spec = pl.BlockSpec((1, 1, bq, d), lambda b, h, qi, ki: (b, h, qi, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, bk, d), lambda b, h, qi, ki: (b, h // G, ki, 0)
+    )
+    row_spec = pl.BlockSpec((1, 1, bq, 1), lambda b, h, qi, ki: (b, h, qi, 0))
     return pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, 1, d), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, bk, 1, d), lambda b, h, qi, ki: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, bk, 1, d), lambda b, h, qi, ki: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, bq, 1, d), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, qi, ki: (b, h, qi)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, qi, ki: (b, h, qi)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, 1, d), lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, d), jnp.float32),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, S, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -307,8 +310,8 @@ def _bwd_dkv_call(
     q, k, v, do, lse, delta, *, scale, causal, window, softcap, bq, bk,
     interpret, policy=None,
 ):
-    B, S, H, d = q.shape
-    T, K = k.shape[1], k.shape[2]
+    B, H, S, d = q.shape
+    K, T = k.shape[1], k.shape[2]
     G = H // K
     nq, nk = S // bq, T // bk
     kernel = functools.partial(
@@ -316,28 +319,23 @@ def _bwd_dkv_call(
         scale=scale, causal=causal, window=window, softcap=softcap,
         bq=bq, bk=bk, nq=nq, n_group=G, seq_len=T, policy=policy,
     )
+    q_spec = pl.BlockSpec(
+        (1, 1, bq, d), lambda b, kh, ki, g, qi: (b, kh * G + g, qi, 0)
+    )
+    kv_spec = pl.BlockSpec(
+        (1, 1, bk, d), lambda b, kh, ki, g, qi: (b, kh, ki, 0)
+    )
+    row_spec = pl.BlockSpec(
+        (1, 1, bq, 1), lambda b, kh, ki, g, qi: (b, kh * G + g, qi, 0)
+    )
     return pl.pallas_call(
         kernel,
         grid=(B, K, nk, G, nq),
-        in_specs=[
-            pl.BlockSpec(
-                (1, bq, 1, d), lambda b, kh, ki, g, qi: (b, qi, kh * G + g, 0)
-            ),
-            pl.BlockSpec((1, bk, 1, d), lambda b, kh, ki, g, qi: (b, ki, kh, 0)),
-            pl.BlockSpec((1, bk, 1, d), lambda b, kh, ki, g, qi: (b, ki, kh, 0)),
-            pl.BlockSpec(
-                (1, bq, 1, d), lambda b, kh, ki, g, qi: (b, qi, kh * G + g, 0)
-            ),
-            pl.BlockSpec((1, 1, bq), lambda b, kh, ki, g, qi: (b, kh * G + g, qi)),
-            pl.BlockSpec((1, 1, bq), lambda b, kh, ki, g, qi: (b, kh * G + g, qi)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, 1, d), lambda b, kh, ki, g, qi: (b, ki, kh, 0)),
-            pl.BlockSpec((1, bk, 1, d), lambda b, kh, ki, g, qi: (b, ki, kh, 0)),
-        ],
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[kv_spec, kv_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((B, T, K, d), jnp.float32),
-            jax.ShapeDtypeStruct((B, T, K, d), jnp.float32),
+            jax.ShapeDtypeStruct((B, K, T, d), jnp.float32),
+            jax.ShapeDtypeStruct((B, K, T, d), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),   # dk accumulator
@@ -350,6 +348,11 @@ def _bwd_dkv_call(
 # ---------------------------------------------------------------------------
 # custom_vjp plumbing
 # ---------------------------------------------------------------------------
+
+def _heads_major(x):
+    """(B, S, H, d) <-> (B, H, S, d); its own inverse."""
+    return x.transpose(0, 2, 1, 3)
+
 
 @functools.lru_cache(maxsize=None)
 def _flash_fn(scale, causal, window, softcap, bq, bk, interpret, policy=None):
@@ -368,23 +371,30 @@ def _flash_fn(scale, causal, window, softcap, bq, bk, interpret, policy=None):
 
     @jax.custom_vjp
     def fn(q, k, v):
-        o, _ = _fwd_call(q, k, v, **kw)
+        o, _ = fwd(q, k, v)
         return o
 
     def fwd(q, k, v):
-        o, lse = _fwd_call(q, k, v, **kw)
-        return o, (q, k, v, o, lse)
+        qt, kt, vt = _heads_major(q), _heads_major(k), _heads_major(v)
+        ot, lse = _fwd_call(qt, kt, vt, **kw)
+        return _heads_major(ot), (qt, kt, vt, ot, lse)
 
     def bwd(res, do):
-        q, k, v, o, lse = res
+        qt, kt, vt, ot, lse = res
+        dot = _heads_major(do)
         # softmax-jacobian correction, rowsum(do * o): cheap elementwise
-        # reduce in plain jnp, laid out (B, H, S) to match lse tiles.
+        # reduce in plain jnp, laid out (B, H, S, 1) to match lse tiles.
         delta = jnp.sum(
-            do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
-        ).transpose(0, 2, 1)
-        dq = _bwd_dq_call(q, k, v, do, lse, delta, **kw)
-        dk, dv = _bwd_dkv_call(q, k, v, do, lse, delta, **kw)
-        return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+            dot.astype(jnp.float32) * ot.astype(jnp.float32), axis=-1,
+            keepdims=True,
+        )
+        dq = _bwd_dq_call(qt, kt, vt, dot, lse, delta, **kw)
+        dk, dv = _bwd_dkv_call(qt, kt, vt, dot, lse, delta, **kw)
+        return (
+            _heads_major(dq).astype(qt.dtype),
+            _heads_major(dk).astype(kt.dtype),
+            _heads_major(dv).astype(vt.dtype),
+        )
 
     fn.defvjp(fwd, bwd)
     return fn

@@ -9,9 +9,11 @@ Dispatch contract (shared by every op here):
   impl="auto"       pallas on TPU, ref elsewhere.  ``REPRO_KERNELS`` in the
                     environment overrides the auto resolution (the CI
                     interpret job sets ``REPRO_KERNELS=interpret`` so kernel
-                    *bodies* — not just the refs — run on every PR).  Shapes
-                    the kernel cannot tile silently fall back to ref: auto
-                    promises a correct answer, not a kernel.
+                    *bodies* — not just the refs — run on every PR).  Off
+                    TPU, shapes the kernel cannot tile fall back to ref.  On
+                    TPU such a shape is a ValueError: the kernel is the main
+                    path there, and a silent swap would run (and time) the
+                    jnp reference in its place.
   impl="pallas"     the compiled Pallas kernel, or ValueError if the shape
                     does not tile.  Never a silent ref fallback — a test
                     that requests the kernel must fail loudly rather than
@@ -28,6 +30,9 @@ cache entry compiled for a different impl.
 All three ops are differentiable under every impl: the ref path by plain
 autodiff, the kernel paths via the custom_vjp backward kernels in their
 modules (flash_attention.py, rmsnorm.py, cross_entropy.py).
+
+``RESOLVED`` records, per op, the impl its latest call resolved to (after
+any fallback), so a run can print and check which path actually ran.
 """
 from __future__ import annotations
 
@@ -36,7 +41,6 @@ import os
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as shd
@@ -47,6 +51,9 @@ from repro.kernels import ref
 from repro.kernels import rmsnorm as rn
 
 _IMPLS = ("auto", "pallas", "interpret", "ref")
+
+# op name -> impl its latest call resolved to (written at trace time)
+RESOLVED: dict[str, str] = {}
 
 
 # ---------------------------------------------------------------------------
@@ -71,6 +78,11 @@ _IMPLS = ("auto", "pallas", "interpret", "ref")
 #   CE / rmsnorm (rows,)              rows over the batch axes; vocab /
 #                                     feature dims stay whole per shard
 #
+# On a mesh of several devices every kernel call goes through shard_map,
+# even when none of its dims partitions (then every device runs the whole
+# op on replicated operands): a bare pallas_call inside a jit over several
+# devices cannot be partitioned, and the TPU compiler refuses it.
+#
 # Axis resolution reuses logical_to_spec, so divisibility fallbacks and the
 # at-most-once mesh-axis rule match with_sharding_constraint exactly.  The
 # ref impl never takes these paths: plain jnp partitions fine under GSPMD.
@@ -80,23 +92,18 @@ _IMPLS = ("auto", "pallas", "interpret", "ref")
 
 def _mesh_axes(logical_axes, shape):
     """(mesh, per-dim mesh-axis entries) under the active sharding context,
-    or None when there is no context / everything resolves to 1 shard."""
+    or None when there is no context or its mesh is a single device."""
     ctx = shd.current_context()
-    if ctx is None:
+    if ctx is None or ctx[0].size == 1:
         return None
     mesh, rules = ctx
     try:
         spec = shd.logical_to_spec(mesh, rules, logical_axes, shape)
     except KeyError:
-        return None
+        spec = P()
     # logical_to_spec strips trailing Nones (jit-cache normalization); pad
     # back to one entry per dim so callers can unpack positionally
     entries = tuple(spec) + (None,) * (len(logical_axes) - len(tuple(spec)))
-    total = 1
-    for e in entries:
-        total *= shd.mesh_axis_size(mesh, e)
-    if total == 1:
-        return None
     return mesh, entries
 
 
@@ -118,14 +125,25 @@ def _resolve_impl(impl: str) -> str:
 
 
 def _reject_untileable(op: str, impl: str, requested: str, detail: str) -> None:
-    """Explicitly-requested kernels never silently fall back to ref."""
-    if requested == "auto":
+    """Kernels never silently fall back to ref when asked for explicitly, or
+    on TPU, where the kernel is the main path."""
+    if requested == "auto" and not _on_tpu():
         return  # caller asked for "a correct answer": ref is fine
     raise ValueError(
-        f"ops.{op}: impl={impl!r} was requested explicitly but the shape "
-        f"does not tile ({detail}); refusing to silently fall back to the "
-        f"jnp reference. Use impl='auto' for best-effort dispatch or fix "
-        f"the block size."
+        f"ops.{op}: impl={impl!r} (requested {requested!r}, backend "
+        f"{jax.default_backend()!r}) but the shape does not tile ({detail}); "
+        f"refusing to silently fall back to the jnp reference. Fix the "
+        f"block size, or request impl='ref' explicitly."
+    )
+
+
+def _shard_map(fn, mesh, in_specs, out_specs):
+    # pallas_call outputs carry no varying-manual-axes annotation, so the
+    # replication check cannot type them: it is off, as the specs say
+    # exactly how every operand partitions
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )
 
 
@@ -142,8 +160,6 @@ def _shard_map_attention(
         # kv heads must partition identically to q heads or GQA groups would
         # straddle shards; fall back to batch-only partitioning
         h_ax = None
-        if shd.mesh_axis_size(mesh, b_ax) == 1:
-            return None
     qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
     kernel = functools.partial(
         fa.flash_attention, scale=1.0, causal=causal, window=window,
@@ -151,10 +167,7 @@ def _shard_map_attention(
         interpret=(impl == "interpret"), policy=policy,
     )
     spec = P(b_ax, None, h_ax, None)
-    return shard_map(
-        kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False,
-    )(qs, k, v)
+    return _shard_map(kernel, mesh, (spec, spec, spec), spec)(qs, k, v)
 
 
 def _shard_map_decode(
@@ -168,7 +181,7 @@ def _shard_map_decode(
     reduction exists because attention never mixes information across heads
     or across batch rows.
     """
-    B, K = q.shape[0], k_pages.shape[2]
+    B, K = q.shape[0], k_pages.shape[1]
     resolved = _mesh_axes(("slots", "kv_heads"), (B, K))
     if resolved is None:
         return None
@@ -186,7 +199,7 @@ def _shard_map_decode(
     q_spec = (
         P(slot_ax, None, kv_ax, None) if multi else P(slot_ax, kv_ax, None)
     )
-    pool_spec = P(None, None, kv_ax, None)
+    pool_spec = P(None, kv_ax, None, None)
     in_specs = [
         q_spec, pool_spec, pool_spec, P(None, None), P(slot_ax, None),
         P(slot_ax, None) if multi else P(slot_ax),
@@ -195,10 +208,7 @@ def _shard_map_decode(
     if k_scale is not None:
         in_specs += [P(None, kv_ax), P(None, kv_ax)]
         args += [k_scale, v_scale]
-    return shard_map(
-        kernel, mesh=mesh, in_specs=tuple(in_specs), out_specs=q_spec,
-        check_rep=False,
-    )(*args)
+    return _shard_map(kernel, mesh, tuple(in_specs), q_spec)(*args)
 
 
 def _row_axis(lead: int):
@@ -272,6 +282,7 @@ def attention(
         impl = "ref"
     if policy is not None and not policy.active:
         policy = None
+    RESOLVED["attention"] = impl
     if impl != "ref":
         out = _shard_map_attention(
             impl, q, k, v, scale, causal=causal, window=window,
@@ -318,7 +329,7 @@ def decode_attention(
 ):
     """Flash-decode: single-query attention over a paged KV cache.
 
-    ``q`` (B, H, d), pools (N, P, K, d) + (N, P) stored positions,
+    ``q`` (B, H, d), pools (N, K, P, d) + (N, P) stored positions,
     ``page_table`` (B, C), ``q_pos`` (B,) (-1 = inactive slot -> zeros).
     With ``k_scale``/``v_scale`` ((N, K) f32) the pools hold int8 blocks,
     dequantized in-kernel (or post-gather in the ref oracle) by their
@@ -326,6 +337,7 @@ def decode_attention(
     tiles, no fallback needed.
     """
     impl = _resolve_impl(impl)
+    RESOLVED["decode_attention"] = impl
     if impl != "ref":
         out = _shard_map_decode(
             False, impl, q, k_pages, v_pages, pos_pages, page_table, q_pos,
@@ -372,7 +384,7 @@ def decode_attention_multi(
     """Multi-query flash-decode: a T-token chunk per slot attends over the
     paged KV cache (speculative-decoding verify and drafter catch-up).
 
-    ``q`` (B, T, H, d), pools (N, P, K, d) + (N, P) stored positions,
+    ``q`` (B, T, H, d), pools (N, K, P, d) + (N, P) stored positions,
     ``page_table`` (B, C), ``q_pos`` (B, T) per-query positions (-1 rows ->
     zeros).  The chunk must already be written into the pages; per-row
     position masking then yields history visibility and intra-chunk
@@ -381,6 +393,7 @@ def decode_attention_multi(
     decode_attention.
     """
     impl = _resolve_impl(impl)
+    RESOLVED["decode_attention_multi"] = impl
     if impl != "ref":
         out = _shard_map_decode(
             True, impl, q, k_pages, v_pages, pos_pages, page_table, q_pos,
@@ -413,6 +426,7 @@ def fused_rmsnorm(x, gain, *, eps: float = 1e-6, block_rows: int = 256,
                   impl: str = "auto"):
     # rmsnorm pads rows internally — every shape tiles, no fallback needed
     impl = _resolve_impl(impl)
+    RESOLVED["fused_rmsnorm"] = impl
     if impl != "ref" and x.ndim >= 2:
         resolved = _row_axis(x.shape[0])
         if resolved is not None:
@@ -422,10 +436,7 @@ def fused_rmsnorm(x, gain, *, eps: float = 1e-6, block_rows: int = 256,
                 rn.rmsnorm, eps=eps, block_rows=block_rows,
                 interpret=(impl == "interpret"),
             )
-            return shard_map(
-                kernel, mesh=mesh, in_specs=(x_spec, P(None)),
-                out_specs=x_spec, check_rep=False,
-            )(x, gain)
+            return _shard_map(kernel, mesh, (x_spec, P(None)), x_spec)(x, gain)
     return _rmsnorm_jit(x, gain, eps=eps, block_rows=block_rows, impl=impl)
 
 
@@ -464,6 +475,7 @@ def softmax_cross_entropy(
             f"V={V} vs vocab chunk {bv}",
         )
         impl = "ref"
+    RESOLVED["softmax_cross_entropy"] = impl
     if impl != "ref":
         resolved = _row_axis(logits.shape[0])
         if resolved is not None:
@@ -476,10 +488,9 @@ def softmax_cross_entropy(
                 ce.cross_entropy, block_rows=block_rows, block_v=bv,
                 interpret=(impl == "interpret"),
             )
-            return shard_map(
-                kernel, mesh=mesh, in_specs=(l_spec, y_spec),
-                out_specs=y_spec, check_rep=False,
-            )(logits, labels)
+            return _shard_map(kernel, mesh, (l_spec, y_spec), y_spec)(
+                logits, labels
+            )
     return _softmax_xent_jit(
         logits, labels, block_rows=block_rows, block_v=bv, impl=impl
     )

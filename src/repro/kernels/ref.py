@@ -91,20 +91,21 @@ def attention_policy_ref(
 
 
 def _gather_kv(k_pages, v_pages, tab, k_scale, v_scale):
-    """Gather pages to f32 (B, C, P, K, d) bands, dequantizing int8 pools
-    with their per-page-per-head scales when given."""
-    k = k_pages[tab].astype(jnp.float32)
+    """Gather kv-head-major (N, K, P, d) pages to f32 (B, C, P, K, d) bands,
+    dequantizing int8 pools with their per-page-per-head scales when
+    given."""
+    k = k_pages[tab].astype(jnp.float32)                # (B, C, K, P, d)
     v = v_pages[tab].astype(jnp.float32)
     if k_scale is not None:
-        k = k * k_scale[tab][:, :, None, :, None]
-        v = v * v_scale[tab][:, :, None, :, None]
-    return k, v
+        k = k * k_scale[tab][..., None, None]
+        v = v * v_scale[tab][..., None, None]
+    return k.swapaxes(2, 3), v.swapaxes(2, 3)
 
 
 def decode_attention_ref(
     q: jax.Array,            # (B, H, d) — one query per decode slot
-    k_pages: jax.Array,      # (N, P, K, d) — paged KV pool
-    v_pages: jax.Array,      # (N, P, K, d)
+    k_pages: jax.Array,      # (N, K, P, d) — paged KV pool
+    v_pages: jax.Array,      # (N, K, P, d)
     pos_pages: jax.Array,    # (N, P) int32 token positions; -1 = empty
     page_table: jax.Array,   # (B, C) int32 page ids per slot
     q_pos: jax.Array,        # (B,) int32 query positions; -1 = inactive slot
@@ -129,7 +130,7 @@ def decode_attention_ref(
     the tight tolerance tier even on quantized pools.
     """
     B, H, d = q.shape
-    N, P, K, _ = k_pages.shape
+    N, K, P, _ = k_pages.shape
     C = page_table.shape[1]
     G = H // K
     tab = jnp.clip(page_table, 0, N - 1)
@@ -155,8 +156,8 @@ def decode_attention_ref(
 
 def decode_attention_multi_ref(
     q: jax.Array,            # (B, T, H, d) — T queries per decode slot
-    k_pages: jax.Array,      # (N, P, K, d) — paged KV pool
-    v_pages: jax.Array,      # (N, P, K, d)
+    k_pages: jax.Array,      # (N, K, P, d) — paged KV pool
+    v_pages: jax.Array,      # (N, K, P, d)
     pos_pages: jax.Array,    # (N, P) int32 token positions; -1 = empty
     page_table: jax.Array,   # (B, C) int32 page ids per slot
     q_pos: jax.Array,        # (B, T) int32 per-query positions; -1 = masked
@@ -179,7 +180,7 @@ def decode_attention_multi_ref(
     at positions >= p.
     """
     B, T, H, d = q.shape
-    N, P, K, _ = k_pages.shape
+    N, K, P, _ = k_pages.shape
     C = page_table.shape[1]
     G = H // K
     tab = jnp.clip(page_table, 0, N - 1)

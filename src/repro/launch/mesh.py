@@ -2,6 +2,10 @@
 jax device state (device count is locked at first jax init, and the dry-run
 must set XLA_FLAGS before that happens).
 
+Every mesh here is built with ``AxisType.Auto`` on every axis: the model
+code places arrays with ``with_sharding_constraint`` and lets GSPMD
+propagate, which ``jax.make_mesh``'s default ``Explicit`` axes refuse.
+
 ``set_scaleout_xla_flags`` appends the async-collective / latency-hiding
 XLA options (the bayespec idiom from SNIPPETS.md) to ``XLA_FLAGS``; call it
 before the first jax operation of the process or it cannot take effect.
@@ -12,6 +16,7 @@ import os
 from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 # Collective-overlap flags for multi-device training: async collectives run
 # on their own stream and the latency-hiding scheduler moves them off the
@@ -71,13 +76,21 @@ def fit_model_parallel(n_devices: int, model_parallel: int) -> Tuple[int, int]:
     return n_devices // model_parallel, model_parallel
 
 
+def _auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-propagated)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: 16x16 = 256 chips (data, model).
     Multi-pod: 2 pods x 256 = 512 chips (pod, data, model) — the 'pod' axis
     is pure DP across pods (cross-pod traffic = one gradient reduction)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1):
@@ -86,7 +99,7 @@ def make_host_mesh(model_parallel: int = 1):
     tensor-parallel axis; it degrades by halving until it divides the
     host's device count (1 CPU -> always (1, 1))."""
     data, model = fit_model_parallel(len(jax.devices()), model_parallel)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_elastic_mesh(n_devices: int, model_parallel: int = 16):
@@ -96,7 +109,7 @@ def make_elastic_mesh(n_devices: int, model_parallel: int = 16):
     ``n_devices`` may be a strict subset of the host's devices (the dead
     nodes' devices are simply not in the mesh)."""
     data, model = fit_model_parallel(n_devices, model_parallel)
-    return jax.make_mesh(
+    return _auto_mesh(
         (data, model), ("data", "model"), devices=jax.devices()[:n_devices]
     )
 
@@ -111,4 +124,4 @@ def make_mesh_shape(shape: Tuple[int, int], *, devices: Optional[list] = None):
         raise ValueError(
             f"mesh shape {shape} needs {n} devices, have {len(devices)}"
         )
-    return jax.make_mesh(shape, ("data", "model"), devices=devices)
+    return _auto_mesh(shape, ("data", "model"), devices=devices)

@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.distributed.sharding import make_rules, shardings as sharding_ctx
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh, make_mesh_shape
 from repro.models.model import build_model
 from repro.serving.engine import DynamicEngine, Engine, EngineConfig
@@ -183,6 +184,7 @@ def main(argv=None):
                          "the Prometheus exposition at exit (see "
                          "docs/observability.md)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = cfg.replace(dtype="float32", kv_dtype=args.kv_dtype)

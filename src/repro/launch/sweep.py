@@ -40,6 +40,7 @@ from repro.core.tuning import (
     train_proxy_batched,
 )
 from repro.distributed.sharding import ShardingRules, named_sharding
+from repro.launch import compile_cache
 
 
 def candidate_mesh(n_devices: Optional[int] = None) -> Mesh:
@@ -226,6 +227,7 @@ def main(argv=None):
     ap.add_argument("--devices", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = (get_config if args.full else get_smoke_config)(args.arch)
     if args.parametrization:

@@ -27,6 +27,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.checkpoint import Checkpointer
 from repro.configs import get_config, get_smoke_config
@@ -38,7 +39,7 @@ from repro.distributed.sharding import (
     named_sharding,
     shardings as sharding_ctx,
 )
-from repro.launch import steps as steps_lib
+from repro.launch import compile_cache, steps as steps_lib
 from repro.launch.mesh import make_host_mesh, set_scaleout_xla_flags
 from repro.models.model import build_model
 from repro.optim import schedules as sched_lib
@@ -113,14 +114,18 @@ def train_loop(
 
     params = model.init(jax.random.PRNGKey(seed))
     params = jax.tree_util.tree_map(jax.device_put, params, p_sh)
-    opt_state = opt.init(params)
+    # placed as the step returns it, so step 1 reuses step 0's executable
+    o_sh = steps_lib.opt_state_shardings(
+        mesh, rules, model.meta, opt, NamedSharding(mesh, P())
+    )
+    opt_state = jax.device_put(opt.init(params), o_sh)
     start_step = 0
 
     ckpt = Checkpointer(ckpt_dir) if ckpt_dir else None
     if ckpt and ckpt.latest_step() is not None:
         (params, opt_state), start_step, extra = ckpt.restore(
             (params, opt_state),
-            shardings=(p_sh, jax.tree_util.tree_map(lambda _: None, opt_state)),
+            shardings=(p_sh, o_sh),
         )
         # restore() device_puts params with the current mesh's shardings —
         # the elastic-restart path when the device count changed.
@@ -177,11 +182,14 @@ def train_loop(
             if ckpt and (t + 1) % ckpt_every == 0:
                 ckpt.save(t + 1, (params, opt_state), async_save=True)
     if ckpt:
-        ckpt.save(steps, (params, opt_state))
+        # drain the in-flight async save first: when steps is a multiple of
+        # ckpt_every it writes the same step directory as this final save
         ckpt.wait()
+        ckpt.save(steps, (params, opt_state))
     return {
         "final_loss": losses[-1] if losses else float("nan"),
         "losses": losses,
+        "step_times": step_times,
         "params": params,
         "steps_run": steps - start_step,
     }
@@ -231,6 +239,7 @@ def main(argv=None):
 
     # must precede any jax operation: XLA reads the flags at backend init
     set_scaleout_xla_flags()
+    compile_cache.enable()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = cfg.replace(parametrization=args.parametrization, dtype="float32",

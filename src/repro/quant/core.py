@@ -55,11 +55,12 @@ def kernel_dot(a: jax.Array, b: jax.Array, policy=None) -> jax.Array:
     """
     mode = getattr(policy, "matmul", "none") if policy is not None else "none"
     if mode == "bf16":
-        return jax.lax.dot(
-            a.astype(jnp.bfloat16),
-            b.astype(jnp.bfloat16),
-            preferred_element_type=jnp.float32,
-        )
+        a, b = a.astype(jnp.bfloat16), b.astype(jnp.bfloat16)
+        if jax.default_backend() == "cpu":
+            # XLA's CPU backend has no bf16 x bf16 -> f32 dot; products of
+            # bf16 values are exact in f32, so upcasting gives the same sum
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return jax.lax.dot(a, b, preferred_element_type=jnp.float32)
     if mode == "int8":
         qa, sa = quantize_int8(a, axis=1)  # (m, k) -> scales (m, 1)
         qb, sb = quantize_int8(b, axis=0)  # (k, n) -> scales (1, n)

@@ -1,7 +1,7 @@
 """Per-page-per-head scale management for the int8 paged KV cache.
 
 Pool layout (see ``repro.serving.kv_cache``): k/v leaves are
-``(..., n_pages, page_size, kv_heads, head_dim)``; the quantized pools
+``(..., n_pages, kv_heads, page_size, head_dim)``; the quantized pools
 add f32 scale leaves ``(..., n_pages, kv_heads)`` — one scale per page
 per kv head, shared by every token and head-dim lane in that page.  That
 granularity is what clears the ~2x byte budget: per-page scales cost
@@ -25,18 +25,18 @@ from repro.quant.core import INT8_MAX, _EPS
 
 
 def abs_scale(x: jax.Array) -> jax.Array:
-    """Per-page-per-head absmax/127 scales for a ``(..., P, K, hd)`` pool.
+    """Per-page-per-head absmax/127 scales for a ``(..., K, P, hd)`` pool.
 
     Reduces the page (token) and head-dim axes, returning ``(..., K)``.
     """
     xf = jnp.abs(x.astype(jnp.float32))
-    return jnp.max(xf, axis=(-3, -1)) / INT8_MAX
+    return jnp.max(xf, axis=(-2, -1)) / INT8_MAX
 
 
 def pack_kv(
     k: jax.Array, v: jax.Array
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Quantize k/v pools ``(..., N, P, K, hd)`` to int8 + per-page scales.
+    """Quantize k/v pools ``(..., N, K, P, hd)`` to int8 + per-page scales.
 
     Returns ``(k_q, v_q, k_scale, v_scale)`` with scales ``(..., N, K)``.
     """
@@ -48,8 +48,8 @@ def pack_kv(
 
 
 def quantize_with(x: jax.Array, scale: jax.Array) -> jax.Array:
-    """Round ``(..., P, K, hd)`` values to int8 using ``(..., K)`` scales."""
-    s = jnp.maximum(scale, _EPS)[..., None, :, None]
+    """Round ``(..., K, P, hd)`` values to int8 using ``(..., K)`` scales."""
+    s = jnp.maximum(scale, _EPS)[..., None, None]
     q = jnp.round(x.astype(jnp.float32) / s)
     return jnp.clip(q, -INT8_MAX, INT8_MAX).astype(jnp.int8)
 
@@ -58,6 +58,6 @@ def unpack_kv(
     k_q: jax.Array, v_q: jax.Array, k_scale: jax.Array, v_scale: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
     """Dequantize int8 pools back to f32 (the ref-oracle view)."""
-    k = k_q.astype(jnp.float32) * k_scale[..., None, :, None]
-    v = v_q.astype(jnp.float32) * v_scale[..., None, :, None]
+    k = k_q.astype(jnp.float32) * k_scale[..., None, None]
+    v = v_q.astype(jnp.float32) * v_scale[..., None, None]
     return k, v

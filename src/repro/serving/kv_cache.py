@@ -5,9 +5,11 @@ Layout
 Each attention layer's cache is a *pool* of fixed-size pages shared by every
 decode slot::
 
-    {"k": (N, P, K, hd), "v": (N, P, K, hd), "pos": (N, P) int32}
+    {"k": (N, K, P, hd), "v": (N, K, P, hd), "pos": (N, P) int32}
 
-(``N`` pages of ``P`` tokens; ``pos`` stores each entry's token position,
+(``N`` pages of ``P`` tokens, kv-head-major so one (page, kv head) is a
+whole ``(P, hd)`` tile for the decode kernels; ``pos`` stores each entry's
+token position,
 -1 = empty — the same position-tagged convention as the dense cache in
 models/attention.py, which remains the train/prefill/oracle path.)  Layers
 in the repeated group are stacked over ``n_groups`` on a leading axis, so
@@ -181,8 +183,8 @@ def init_pools(
     def pool(n_pages, stacked):
         lead = (cfg.n_groups,) if stacked else ()
         p = {
-            "k": jnp.zeros((*lead, n_pages, spec.page_size, K, hd), dtype),
-            "v": jnp.zeros((*lead, n_pages, spec.page_size, K, hd), dtype),
+            "k": jnp.zeros((*lead, n_pages, K, spec.page_size, hd), dtype),
+            "v": jnp.zeros((*lead, n_pages, K, spec.page_size, hd), dtype),
             "pos": jnp.full((*lead, n_pages, spec.page_size), -1, jnp.int32),
         }
         if kv_name == "int8":
@@ -220,8 +222,8 @@ def constrain_pools(pools: Dict[str, Any]) -> Dict[str, Any]:
     def one(p, stacked):
         la = ("layers",) if stacked else ()
         q = {
-            "k": shard(p["k"], *la, "pages", None, "kv_heads", "head_dim"),
-            "v": shard(p["v"], *la, "pages", None, "kv_heads", "head_dim"),
+            "k": shard(p["k"], *la, "pages", "kv_heads", None, "head_dim"),
+            "v": shard(p["v"], *la, "pages", "kv_heads", None, "head_dim"),
             "pos": shard(p["pos"], *la, "pages", None),
         }
         if "k_scale" in p:
@@ -262,7 +264,7 @@ def pool_bytes(cfg, spec: PagedSpec) -> int:
 # ---------------------------------------------------------------------------
 
 def paged_cache_write(
-    cache: Dict[str, jax.Array],   # {"k": (N,P,K,hd), "v": ..., "pos": (N,P)}
+    cache: Dict[str, jax.Array],   # {"k": (N,K,P,hd), "v": ..., "pos": (N,P)}
     k_new: jax.Array,              # (B, T, K, hd)
     v_new: jax.Array,
     positions: jax.Array,          # (B, T) int32; -1 = dropped
@@ -299,16 +301,22 @@ def paged_cache_write(
     if "k_scale" in cache:
         k, ks = _quantized_write(cache["k"], cache["k_scale"], k_new, page, off)
         v, vs = _quantized_write(cache["v"], cache["v_scale"], v_new, page, off)
-        k = shard(k, "pages", None, "kv_heads", "head_dim")
-        v = shard(v, "pages", None, "kv_heads", "head_dim")
+        k = shard(k, "pages", "kv_heads", None, "head_dim")
+        v = shard(v, "pages", "kv_heads", None, "head_dim")
         return {"k": k, "v": v, "pos": p,
                 "k_scale": shard(ks, "pages", "kv_heads"),
                 "v_scale": shard(vs, "pages", "kv_heads")}
-    k = cache["k"].at[page, off].set(k_new.astype(cache["k"].dtype))
-    v = cache["v"].at[page, off].set(v_new.astype(cache["v"].dtype))
-    k = shard(k, "pages", None, "kv_heads", "head_dim")
-    v = shard(v, "pages", None, "kv_heads", "head_dim")
+    k = _write_tokens(cache["k"], page, off, k_new.astype(cache["k"].dtype))
+    v = _write_tokens(cache["v"], page, off, v_new.astype(cache["v"].dtype))
+    k = shard(k, "pages", "kv_heads", None, "head_dim")
+    v = shard(v, "pages", "kv_heads", None, "head_dim")
     return {"k": k, "v": v, "pos": p}
+
+
+def _write_tokens(store, page, off, val):
+    """``store[page, :, off] = val``: token-major ``val`` (..., K, hd) into
+    the kv-head-major pool (N, K, P, hd) at cells (page, off)."""
+    return store.at[page, :, off].set(val)
 
 
 def _quantized_write(store, scale, x_new, page, off):
@@ -337,14 +345,14 @@ def _quantized_write(store, scale, x_new, page, off):
         scale[page_c] / jnp.maximum(scale1[page_c], _SCALE_EPS),
         1.0,
     )                                                    # (B, T, K)
-    old = store[page_c].astype(jnp.float32)              # (B, T, P, K, hd)
-    requant = jnp.round(old * ratio[:, :, None, :, None])
+    old = store[page_c].astype(jnp.float32)              # (B, T, K, P, hd)
+    requant = jnp.round(old * ratio[..., None, None])
     store1 = store.at[page].set(
         jnp.clip(requant, -INT8_MAX, INT8_MAX).astype(jnp.int8)
     )
     sn = jnp.maximum(scale1[page_c], _SCALE_EPS)[..., None]
     q_tok = jnp.clip(jnp.round(xf / sn), -INT8_MAX, INT8_MAX)
-    return store1.at[page, off].set(q_tok.astype(jnp.int8)), scale1
+    return _write_tokens(store1, page, off, q_tok.astype(jnp.int8)), scale1
 
 
 # ---------------------------------------------------------------------------
@@ -423,18 +431,27 @@ def admit_slot(
                 new = {"k": kq, "v": vq, "pos": pos_new,
                        "k_scale": ks, "v_scale": vs}
             else:
-                lead = (slice(None),) if stacked else ()
                 new = {
-                    "k": pool["k"].at[(*lead, page, off)].set(
-                        ksrc.astype(pool["k"].dtype)
+                    "k": _admit_tokens(
+                        pool["k"], page, off, ksrc.astype(pool["k"].dtype),
+                        stacked,
                     ),
-                    "v": pool["v"].at[(*lead, page, off)].set(
-                        vsrc.astype(pool["v"].dtype)
+                    "v": _admit_tokens(
+                        pool["v"], page, off, vsrc.astype(pool["v"].dtype),
+                        stacked,
                     ),
                     "pos": pos_new,
                 }
             out[section][key] = {"attn": new}
     return out
+
+
+def _admit_tokens(store, page, off, val, stacked):
+    """_write_tokens for one admission, mapped over the layer axis of a
+    stacked (group) pool."""
+    if stacked:
+        return jax.vmap(lambda s, x: _write_tokens(s, page, off, x))(store, val)
+    return _write_tokens(store, page, off, val)
 
 
 def _admit_quantized(store, scale, src, page, off, rows, stacked):
@@ -450,7 +467,7 @@ def _admit_quantized(store, scale, src, page, off, rows, stacked):
     page_c = jnp.clip(page, 0, n_pool - 1)
     sn = jnp.maximum(scale[(*lead, page_c)], _SCALE_EPS)[..., None]
     q = jnp.clip(jnp.round(sf / sn), -INT8_MAX, INT8_MAX).astype(jnp.int8)
-    return store.at[(*lead, page, off)].set(q), scale
+    return _admit_tokens(store, page, off, q, stacked), scale
 
 
 # ---------------------------------------------------------------------------
@@ -502,13 +519,13 @@ def gather_slot(
     oracle view used by tests."""
     N = pool["pos"].shape[-2]
     tab = jnp.clip(table_row, 0, N - 1)
-    K, hd = pool["k"].shape[-2:]
-    k, v = pool["k"][tab], pool["v"][tab]
+    K, hd = pool["k"].shape[-3], pool["k"].shape[-1]
+    k, v = pool["k"][tab], pool["v"][tab]               # (C, K, P, hd)
     if "k_scale" in pool:
-        k = k.astype(jnp.float32) * pool["k_scale"][tab][:, None, :, None]
-        v = v.astype(jnp.float32) * pool["v_scale"][tab][:, None, :, None]
+        k = k.astype(jnp.float32) * pool["k_scale"][tab][..., None, None]
+        v = v.astype(jnp.float32) * pool["v_scale"][tab][..., None, None]
     return {
-        "k": k.reshape(-1, K, hd),
-        "v": v.reshape(-1, K, hd),
+        "k": k.swapaxes(1, 2).reshape(-1, K, hd),
+        "v": v.swapaxes(1, 2).reshape(-1, K, hd),
         "pos": pool["pos"][tab].reshape(-1),
     }

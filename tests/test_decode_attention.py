@@ -41,8 +41,8 @@ def _paged_case(B, K, G, d, P, C, T, seed=0, permute=True):
     else:
         tab = jnp.arange(B * C).reshape(B, C)
     tab = tab.astype(jnp.int32)
-    kp = jnp.zeros((N, P, K, d), jnp.float32)
-    vp = jnp.zeros((N, P, K, d), jnp.float32)
+    kp = jnp.zeros((N, K, P, d), jnp.float32)   # kv-head-major pool
+    vp = jnp.zeros((N, K, P, d), jnp.float32)
     pos = jnp.full((N, P), -1, jnp.int32)
     # scatter the first T tokens of each slot into its pages, page-major
     t = jnp.arange(T)
@@ -52,8 +52,8 @@ def _paged_case(B, K, G, d, P, C, T, seed=0, permute=True):
     )  # (B, T)
     offs = jnp.broadcast_to((t % P)[None], (B, T))
     b_idx = jnp.broadcast_to(jnp.arange(B)[:, None], (B, T))
-    kp = kp.at[pages, offs].set(k_dense[b_idx, t[None, :]])
-    vp = vp.at[pages, offs].set(v_dense[b_idx, t[None, :]])
+    kp = kp.at[pages, :, offs].set(k_dense[b_idx, t[None, :]])
+    vp = vp.at[pages, :, offs].set(v_dense[b_idx, t[None, :]])
     pos = pos.at[pages, offs].set(jnp.broadcast_to(t[None], (B, T)))
     q_pos = jnp.full((B,), T - 1, jnp.int32)
     return q, kp, vp, pos, tab, q_pos, k_dense[:, :T], v_dense[:, :T]
@@ -140,7 +140,7 @@ def test_ring_stale_entries_masked():
     q, kp, vp, pos, tab, q_pos, kd, vd = _paged_case(B, K, G, d, P, C, T)
     # poison every entry older than the window; output must not move
     old = (q_pos[0] - pos) >= window
-    vp2 = jnp.where(old[..., None, None], 1e4, vp)
+    vp2 = jnp.where(old[:, None, :, None], 1e4, vp)
     a = flash_decode(q, kp, vp, pos, tab, q_pos, scale=0.125, window=window,
                      interpret=True)
     b = flash_decode(q, kp, vp2, pos, tab, q_pos, scale=0.125, window=window,

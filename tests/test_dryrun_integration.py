@@ -14,6 +14,7 @@ SCRIPT = textwrap.dedent(
     """
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import sys
     sys.path.insert(0, sys.argv[1])
     import jax
@@ -25,10 +26,11 @@ SCRIPT = textwrap.dedent(
     from repro.launch import specs as specs_lib
     from repro.launch import steps as steps_lib
     from repro.launch.dryrun import collective_census
+    from repro.launch.mesh import make_mesh_shape
     from repro.models.model import build_model
     from repro.optim.optimizer import Optimizer
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh_shape((2, 4))
     arch = sys.argv[2]
     cfg = get_smoke_config(arch)
     model = build_model(cfg)

@@ -151,6 +151,34 @@ def test_attention_auto_falls_back_on_untileable():
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
+@pytest.mark.parametrize("op", ["attention", "softmax_cross_entropy"])
+def test_auto_never_falls_back_on_tpu(op, monkeypatch):
+    """On TPU the kernel is the main path: an untileable shape under
+    impl="auto" raises instead of running (and being timed as) the ref."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.delenv("REPRO_KERNELS", raising=False)
+    with pytest.raises(ValueError, match="refusing to silently fall back"):
+        if op == "attention":
+            q, k, v = _qkv(1, 100, 100, 4, 2, 32, jnp.float32)
+            ops.attention(q, k, v, scale=0.1, block_q=64, block_k=64)
+        else:
+            x = jax.random.normal(jax.random.PRNGKey(0), (8, 100))
+            ops.softmax_cross_entropy(x, jnp.zeros((8,), jnp.int32),
+                                      block_v=64)
+
+
+def test_resolved_records_the_impl_that_ran(monkeypatch):
+    """ops.RESOLVED names what each op's latest call ran, after fallback."""
+    monkeypatch.delenv("REPRO_KERNELS", raising=False)
+    q, k, v = _qkv(1, 100, 100, 4, 2, 32, jnp.float32)
+    ops.attention(q, k, v, scale=0.1, block_q=64, block_k=64)
+    assert ops.RESOLVED["attention"] == "ref"           # untileable -> ref
+    q, k, v = _qkv(1, 64, 64, 4, 2, 32, jnp.float32)
+    ops.attention(q, k, v, scale=0.1, block_q=64, block_k=64,
+                  impl="interpret")
+    assert ops.RESOLVED["attention"] == "interpret"
+
+
 def test_cross_entropy_explicit_impl_never_silently_falls_back():
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 100))  # 100 % 64 != 0
     with pytest.raises(ValueError, match="refusing to silently fall back"):

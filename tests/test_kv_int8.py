@@ -48,8 +48,8 @@ def _paged_case(B, K, G, d, P, C, T, seed=0):
     v_dense = jax.random.normal(ks[2], (B, C * P, K, d), jnp.float32)
     tab = ((jnp.arange(C)[None, :] * B + jnp.arange(B)[:, None] + 2) % N)
     tab = tab.astype(jnp.int32)
-    kp = jnp.zeros((N, P, K, d), jnp.float32)
-    vp = jnp.zeros((N, P, K, d), jnp.float32)
+    kp = jnp.zeros((N, K, P, d), jnp.float32)   # kv-head-major pool
+    vp = jnp.zeros((N, K, P, d), jnp.float32)
     pos = jnp.full((N, P), -1, jnp.int32)
     t = jnp.arange(T)
     cols = t // P
@@ -58,8 +58,8 @@ def _paged_case(B, K, G, d, P, C, T, seed=0):
     )
     offs = jnp.broadcast_to((t % P)[None], (B, T))
     b_idx = jnp.broadcast_to(jnp.arange(B)[:, None], (B, T))
-    kp = kp.at[pages, offs].set(k_dense[b_idx, t[None, :]])
-    vp = vp.at[pages, offs].set(v_dense[b_idx, t[None, :]])
+    kp = kp.at[pages, :, offs].set(k_dense[b_idx, t[None, :]])
+    vp = vp.at[pages, :, offs].set(v_dense[b_idx, t[None, :]])
     pos = pos.at[pages, offs].set(jnp.broadcast_to(t[None], (B, T)))
     q_pos = jnp.full((B,), T - 1, jnp.int32)
     return q, kp, vp, pos, tab, q_pos, k_dense[:, :T], v_dense[:, :T]
@@ -151,8 +151,8 @@ def test_int8_ops_dispatch_and_inactive_rows():
 
 def _int8_cache(N, P, K, hd):
     return {
-        "k": jnp.zeros((N, P, K, hd), jnp.int8),
-        "v": jnp.zeros((N, P, K, hd), jnp.int8),
+        "k": jnp.zeros((N, K, P, hd), jnp.int8),
+        "v": jnp.zeros((N, K, P, hd), jnp.int8),
         "pos": jnp.full((N, P), -1, jnp.int32),
         "k_scale": jnp.zeros((N, K), jnp.float32),
         "v_scale": jnp.zeros((N, K), jnp.float32),
@@ -183,15 +183,15 @@ def test_paged_write_scale_grows_and_requants():
     s2 = np.asarray(c2["k_scale"])
     assert np.all(s2 >= s1 - 1e-12)                    # monotone while live
     assert np.all(s2[0] > s1[0])
-    deq0 = np.asarray(c2["k"][0, 0], np.float32) * s2[0][:, None]
+    deq0 = np.asarray(c2["k"][0, :, 0], np.float32) * s2[0][:, None]
     assert np.all(np.abs(deq0 - np.asarray(small[0, 0])) <= s2[0][:, None])
 
     # a small write cannot shrink the scale, and untouched cells of the
     # page stay bit-identical (requant ratio is exactly 1.0)
     c3 = _write(c2, small, small, jnp.array([[2]]), tab, P)
     np.testing.assert_array_equal(np.asarray(c3["k_scale"]), s2)
-    np.testing.assert_array_equal(np.asarray(c3["k"][0, :2]),
-                                  np.asarray(c2["k"][0, :2]))
+    np.testing.assert_array_equal(np.asarray(c3["k"][0, :, :2]),
+                                  np.asarray(c2["k"][0, :, :2]))
     assert np.asarray(c3["pos"][0]).tolist() == [0, 1, 2, -1]
 
 

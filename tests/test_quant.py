@@ -57,17 +57,17 @@ def test_quantize_int8_roundtrip_halfstep():
 
 def test_pack_unpack_kv_halfstep():
     ks = jax.random.split(jax.random.PRNGKey(1), 2)
-    k = jax.random.normal(ks[0], (6, 4, 2, 8), jnp.float32)   # (N, P, K, hd)
-    v = jax.random.normal(ks[1], (6, 4, 2, 8), jnp.float32)
+    k = jax.random.normal(ks[0], (6, 2, 4, 8), jnp.float32)   # (N, K, P, hd)
+    v = jax.random.normal(ks[1], (6, 2, 4, 8), jnp.float32)
     k_q, v_q, k_scale, v_scale = pack_kv(k, v)
     assert k_q.dtype == v_q.dtype == jnp.int8
     assert k_scale.shape == v_scale.shape == (6, 2)           # per page/head
     kd, vd = unpack_kv(k_q, v_q, k_scale, v_scale)
     assert bool(jnp.all(
-        jnp.abs(kd - k) <= k_scale[:, None, :, None] / 2 + 1e-7
+        jnp.abs(kd - k) <= k_scale[:, :, None, None] / 2 + 1e-7
     ))
     assert bool(jnp.all(
-        jnp.abs(vd - v) <= v_scale[:, None, :, None] / 2 + 1e-7
+        jnp.abs(vd - v) <= v_scale[:, :, None, None] / 2 + 1e-7
     ))
 
 

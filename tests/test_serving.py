@@ -214,11 +214,18 @@ def test_engine_rejects_degenerate_dimensions(global_m):
 
 def test_generate_key_threading_deterministic(global_m):
     cfg, model, params = global_m
-    prompts, _ = _prompts(cfg, R=2, L=8, seed=8)
-    a = generate(model, params, prompts, 5, temperature=1.0, seed=3)
-    b = generate(model, params, prompts, 5, temperature=1.0, seed=3)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    c = generate(model, params, prompts, 5, temperature=1.0, seed=4)
+    # prompts chosen by property: the untrained model is peaked enough
+    # that some prompts sample their argmax under every seed.  A seed that
+    # were not threaded would give equal streams for *every* prompt, so one
+    # prompt set whose streams differ proves the threading.
+    for pseed in range(8, 24):
+        prompts, _ = _prompts(cfg, R=2, L=8, seed=pseed)
+        a = generate(model, params, prompts, 5, temperature=1.0, seed=3)
+        b = generate(model, params, prompts, 5, temperature=1.0, seed=3)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        c = generate(model, params, prompts, 5, temperature=1.0, seed=4)
+        if not np.array_equal(np.asarray(a), np.asarray(c)):
+            break
     assert not np.array_equal(np.asarray(a), np.asarray(c))
 
 
@@ -265,8 +272,8 @@ def test_generate_eos_early_stop(global_m):
 
 def _empty_pool(n_pages, P=4, K=2, hd=4):
     return {
-        "k": jnp.zeros((n_pages, P, K, hd), jnp.float32),
-        "v": jnp.zeros((n_pages, P, K, hd), jnp.float32),
+        "k": jnp.zeros((n_pages, K, P, hd), jnp.float32),
+        "v": jnp.zeros((n_pages, K, P, hd), jnp.float32),
         "pos": jnp.full((n_pages, P), -1, jnp.int32),
     }
 
@@ -489,10 +496,11 @@ def _assert_pools_equal(pools_a, pools_b, atol=2e-5):
         np.testing.assert_array_equal(pos_a, pos_b)
         mask = pos_a >= 0
         for key in ("k", "v"):
-            np.testing.assert_allclose(
-                np.asarray(pa[key])[mask], np.asarray(pb[key])[mask],
-                atol=atol,
-            )
+            # token-major (..., N, P, K, hd) view so the (..., N, P) mask
+            # selects written rows of the kv-head-major pool
+            ka = np.moveaxis(np.asarray(pa[key]), -3, -2)
+            kb = np.moveaxis(np.asarray(pb[key]), -3, -2)
+            np.testing.assert_allclose(ka[mask], kb[mask], atol=atol)
 
 
 def test_dynamic_one_shot_matches_static(global_m, global_engine):

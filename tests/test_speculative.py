@@ -70,7 +70,7 @@ def test_chunk_write_equals_single_writes(ring):
     positions = jnp.array([[3, 4, 5, 6, 7], [-1, 9, 10, 11, 12]], jnp.int32)
     active = jnp.array([True, True])
     blank = {
-        "k": jnp.zeros((N, P, K, hd)), "v": jnp.zeros((N, P, K, hd)),
+        "k": jnp.zeros((N, K, P, hd)), "v": jnp.zeros((N, K, P, hd)),
         "pos": jnp.full((N, P), -1, jnp.int32),
     }
     chunk = kv_cache.paged_cache_write(
@@ -209,22 +209,26 @@ def test_spec_eos_mid_draft_retirement(global_m):
     there: nothing after the EOS is emitted, lengths match the
     non-speculative engine exactly."""
     cfg, model, params = global_m
-    # seed chosen so the untrained model's greedy streams are not all
-    # constant (most random prompts hit a single-token attractor, which
-    # leaves no mid-stream EOS candidate)
-    prompts, lens = _prompts(cfg, R=5, L=16, seed=2)
-    probe = Engine(model, EngineConfig(**_ECFG)).serve(params, prompts, lens)
-    toks = np.asarray(probe["tokens"])
     Gmax = _ECFG["max_gen_len"]
-    # pick an EOS the greedy stream actually emits such that some row's
-    # first hit lands strictly inside the budget — mid-run retirement
+    probe_engine = Engine(model, EngineConfig(**_ECFG))
+    # the prompts are chosen by property, not by seed: most random prompts
+    # send the untrained model's greedy stream into a single-token
+    # attractor, which leaves no mid-stream EOS candidate.  Pick the first
+    # prompt set with an EOS the greedy stream actually emits such that
+    # some row's first hit lands strictly inside the budget — mid-run
+    # retirement.
     eos = -1
-    for e in np.unique(toks):
-        first = np.where(
-            (toks == e).any(1), (toks == e).argmax(1) + 1, Gmax
-        )
-        if np.any((first > 1) & (first < Gmax)):
-            eos = int(e)
+    for seed in range(2, 34, 2):
+        prompts, lens = _prompts(cfg, R=5, L=16, seed=seed)
+        toks = np.asarray(probe_engine.serve(params, prompts, lens)["tokens"])
+        for e in np.unique(toks):
+            first = np.where(
+                (toks == e).any(1), (toks == e).argmax(1) + 1, Gmax
+            )
+            if np.any((first > 1) & (first < Gmax)):
+                eos = int(e)
+                break
+        if eos >= 0:
             break
     assert eos >= 0, toks
     base = Engine(model, EngineConfig(**_ECFG, eos_token_id=eos))
@@ -320,17 +324,26 @@ def test_identical_requests_get_independent_streams(global_m):
     (keys fold the request id, not just the position)."""
     cfg, model, params = global_m
     eng = Engine(model, EngineConfig(**_ECFG))
-    p = jax.random.randint(jax.random.PRNGKey(5), (1, 16), 0, cfg.vocab_size)
-    prompts = jnp.concatenate([p, p])
     lens = jnp.array([16, 16], jnp.int32)
     # temp 2: the untrained model's logits are peaked enough that temp 1
-    # sampling is near-deterministic and both rows would agree by chance
-    out = eng.serve(
-        params, prompts, lens, temperature=jnp.array([2.0, 2.0]), seed=0
-    )
-    assert not np.array_equal(
-        np.asarray(out["tokens"][0]), np.asarray(out["tokens"][1])
-    )
+    # sampling is near-deterministic and both rows would agree by chance.
+    # Even at temp 2 some prompts sit in a peaked region, so the prompt is
+    # chosen by property: keys that ignored the request id would give equal
+    # rows for *every* prompt, so one prompt with unequal rows proves it.
+    rows_differ = []
+    for key in range(5, 21):
+        p = jax.random.randint(
+            jax.random.PRNGKey(key), (1, 16), 0, cfg.vocab_size
+        )
+        out = eng.serve(
+            params, jnp.concatenate([p, p]), lens,
+            temperature=jnp.array([2.0, 2.0]), seed=0,
+        )
+        toks = np.asarray(out["tokens"])
+        rows_differ.append(not np.array_equal(toks[0], toks[1]))
+        if rows_differ[-1]:
+            break
+    assert rows_differ[-1], rows_differ
 
 
 # ---------------------------------------------------------------------------
