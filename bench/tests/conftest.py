@@ -1,0 +1,11 @@
+"""The benchmark's own tests import the harness from ``bench/`` and the
+program from ``src/``."""
+from __future__ import annotations
+
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+for p in (BENCH, BENCH.parent / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
