@@ -133,7 +133,11 @@ def train_loop(
         print(f"[train] resumed from step {start_step}")
 
     pipe = make_pipeline(cfg.vocab_size, seq_len, batch_size, seed=seed)
+    make_batch = lambda t: {
+        k: batch_sh(jnp.asarray(v)) for k, v in pipe.batch(t).items()
+    }
     jit_step = jax.jit(step_fn, donate_argnums=(0, 1))
+    tracer = obs.tracer if obs is not None else None
 
     losses = []
     step_times = []
@@ -148,16 +152,18 @@ def train_loop(
                     ckpt.wait()
                 raise SimulatedFailure(f"injected node failure at step {t}")
             t0 = time.time()
-            batch = {
-                k: batch_sh(jnp.asarray(v)) for k, v in pipe.batch(t).items()
-            }
-            if obs is not None and obs.tracer is not None:
-                with obs.tracer.span("train_step", phase="train_step", step=t):
+            if tracer is not None:
+                # both spans also land on the profiler's trace, where the
+                # device idles through train.data (the host makes the batch)
+                with tracer.span("train.data", step=t):
+                    batch = make_batch(t)
+                with tracer.span("train_step", phase="train_step", step=t):
                     params, opt_state, metrics = jit_step(
                         params, opt_state, batch
                     )
                     loss = float(metrics["loss"])
             else:
+                batch = make_batch(t)
                 params, opt_state, metrics = jit_step(params, opt_state, batch)
                 loss = float(metrics["loss"])
             dt = time.time() - t0

@@ -11,8 +11,8 @@ docs/observability.md for the metric catalog and interpretation guide):
     a host ring buffer; a width-exponent drift detector flags scales that
     depart the parametrization's prediction (Fig. 5 as a monitor).
   - :mod:`repro.obs.trace` — host-side span tracer (JSONL, monotonic
-    clock) for request phases and sweep candidate lifecycles, with
-    optional ``jax.profiler`` trace-dump integration.
+    clock) for request phases and sweep candidate lifecycles; its spans
+    are also annotations on the ``jax.profiler`` trace.
 
 Instrumentation is off by default everywhere, and never device-side for
 serving: attaching a :class:`ServeObs` cannot change a traced program, so
@@ -43,7 +43,7 @@ from repro.obs.telemetry import (
     loglog_slope,
     update_ratios,
 )
-from repro.obs.trace import PHASE_KERNELS, Tracer, load_jsonl
+from repro.obs.trace import Tracer, load_jsonl
 
 
 @dataclasses.dataclass
@@ -76,7 +76,6 @@ __all__ = [
     "flatten_stats",
     "loglog_slope",
     "update_ratios",
-    "PHASE_KERNELS",
     "Tracer",
     "load_jsonl",
 ]

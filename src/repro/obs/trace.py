@@ -12,13 +12,11 @@ Chrome-trace-flavored events on a single monotonic clock:
 JSON object per line (:meth:`Tracer.dump` / ``path=``), so logs stream and
 cheap tools (jq, pandas) read them without a closing bracket.
 
-Device-side work never appears here directly — a span brackets the *host's*
-view of a dispatched step (which, in the dynamic engine, is synchronized by
-its per-step ``device_get``, so span durations are honest).  For kernel
-attribution, spans carry a ``kernel`` arg naming the Pallas kernels that
-dominate the phase (the names benchmarks/roofline.py profiles), and
-``profile_dir`` wraps a region in ``jax.profiler`` so the JSONL spans can be
-cross-referenced against the XLA trace dump's kernel timeline.
+Every :meth:`Tracer.span` is also written into the profiler's own trace
+through :func:`annotate`, so while ``jax.profiler`` records, host phases sit
+on the same clock as the device's ``XLA Ops`` line and the idle gaps between
+ops can be read against what the host was doing.  With no profiler running
+an annotation costs about a microsecond.
 """
 from __future__ import annotations
 
@@ -27,15 +25,14 @@ import json
 import time
 from typing import Any, Dict, Iterator, List, Optional
 
-# host phase -> the roofline-profiled kernels that dominate it
-# (benchmarks/roofline.py kernel names; see docs/observability.md)
-PHASE_KERNELS: Dict[str, str] = {
-    "prefill": "flash_attention_fwd",
-    "chunk_prefill": "decode_attention_multi",
-    "decode": "decode_attention",
-    "verify": "decode_attention_multi",
-    "train_step": "flash_attention_fwd+flash_attention_bwd+chunked_cross_entropy",
-}
+from jax.profiler import TraceAnnotation
+
+
+def annotate(name: str, **args: Any) -> TraceAnnotation:
+    """A span on the profiler's trace, for use as a context manager; ``args``
+    (ints, floats, strings) come back as the event's stats.  Its
+    ``set_metadata(**args)`` adds args while it is open."""
+    return TraceAnnotation(name, **args)
 
 
 class Tracer:
@@ -43,20 +40,15 @@ class Tracer:
 
     ``path`` streams events as JSONL while recording; without it events
     accumulate in ``self.events`` (bounded by ``max_events``) for a later
-    :meth:`dump`.  ``profile_dir`` arms :meth:`profile` to wrap a region in
-    ``jax.profiler.trace`` (the XLA trace dump); it is a no-op when unset,
-    so call sites don't need to branch.
+    :meth:`dump`.  ``t0`` is the ``time.monotonic()`` value at which the
+    events' ``ts`` is 0.
     """
 
-    def __init__(self, path: Optional[str] = None,
-                 profile_dir: Optional[str] = None,
-                 max_events: int = 200_000):
+    def __init__(self, path: Optional[str] = None, max_events: int = 200_000):
         self.t0 = time.monotonic()
         self.events: List[Dict[str, Any]] = []
         self.max_events = max_events
         self.dropped = 0
-        self.profile_dir = profile_dir
-        self._profiling = False
         self._file = open(path, "w") if path else None
 
     # ------------------------------------------------------------------
@@ -78,14 +70,12 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, **args: Any) -> Iterator[None]:
-        """Complete span around a host-side phase.  Adds the dominating
-        kernel names for phases the roofline profiles (args win on clash)."""
-        phase = args.get("phase", name)
-        if phase in PHASE_KERNELS and "kernel" not in args:
-            args["kernel"] = PHASE_KERNELS[phase]
+        """Complete span around a host-side phase, recorded here and as an
+        annotation of the same name and args on the profiler's trace."""
         ts = self.now_us()
         try:
-            yield
+            with annotate(name, **args):
+                yield
         finally:
             self._emit({"name": name, "ph": "X", "ts": ts,
                         "dur": self.now_us() - ts,
@@ -99,33 +89,12 @@ class Tracer:
         per-step path): the caller times the region itself — usually with
         stamps it already takes for other bookkeeping — and this just emits,
         skipping the generator-contextmanager machinery of :meth:`span`.
+        It writes nothing to the profiler's trace (the region is over).
         """
-        phase = args.get("phase", name)
-        if phase in PHASE_KERNELS and "kernel" not in args:
-            args["kernel"] = PHASE_KERNELS[phase]
         self._emit({"name": name, "ph": "X",
                     "ts": (t_start - self.t0) * 1e6,
                     "dur": (t_end - t_start) * 1e6,
                     **({"args": args} if args else {})})
-
-    @contextlib.contextmanager
-    def profile(self, label: str = "obs") -> Iterator[None]:
-        """Wrap a region in ``jax.profiler.trace`` when ``profile_dir`` is
-        set (else a pure no-op).  Non-reentrant by construction —
-        jax.profiler allows one active trace — so nested calls no-op too."""
-        if self.profile_dir is None or self._profiling:
-            yield
-            return
-        import jax
-
-        self._profiling = True
-        self.event("profile_start", dir=self.profile_dir, label=label)
-        try:
-            with jax.profiler.trace(self.profile_dir):
-                yield
-        finally:
-            self._profiling = False
-            self.event("profile_stop", label=label)
 
     # ------------------------------------------------------------------
     def dump(self, path: str) -> int:

@@ -85,6 +85,7 @@ from repro.distributed.sharding import (
     shard,
     shardings as sharding_ctx,
 )
+from repro.obs.trace import annotate
 from repro.serving import kv_cache, sampling
 from repro.serving.allocator import BlockManager
 
@@ -749,6 +750,93 @@ class Engine:
         }
 
 
+class _Span:
+    """One open span: a profiler annotation, and the start and args of
+    the ``complete`` event it becomes."""
+
+    __slots__ = ("name", "t", "args", "ann")
+
+    def __init__(self, name: str, t: float, args: Dict[str, Any]):
+        self.name, self.t, self.args = name, t, args
+        self.ann = annotate(name, **args)
+        self.ann.__enter__()
+
+    def close(self, t: float, out: list) -> None:
+        self.ann.__exit__(None, None, None)
+        out.append((self.name, self.t, t, self.args))
+
+
+class _LoopSpans:
+    """The host loop of one ``DynamicEngine.serve``, on the profiler's trace
+    and in the tracer's JSONL alike.
+
+    ``engine.serve`` around the loop; inside it ``engine.wait_arrival``
+    around each idle sleep, and one ``engine.step`` per dispatched step whose
+    phases (``engine.admit``, ``engine.prepare``, ``engine.dispatch``,
+    ``engine.sync``, ``engine.bookkeep``) follow one another without
+    overlap: :meth:`begin` ends the running phase and starts the next at one
+    ``time.monotonic()`` stamp.  Each span is an annotation while it is open
+    and, once its step or sleep is over, a ``complete`` event of the same
+    name and args.  Those events go out at a step's end, after the loop's
+    own ``step`` event, so a tracer whose ``complete`` ends the serve (the
+    bench's warm-up) ends it after the first step.  Leaving the ``with``
+    block by an exception ends the open annotations and sends nothing
+    more.
+    """
+
+    def __init__(self, tracer, t0: float, **args):
+        self._tracer = tracer
+        self._serve = _Span("engine.serve", t0, args)
+        self._step = self._phase = None
+        self._done: list = []
+
+    def __enter__(self) -> "_LoopSpans":
+        return self
+
+    def __exit__(self, exc_type, *exc) -> None:
+        t = time.monotonic()
+        self.end(t, send=exc_type is None)
+        self._serve.close(t, self._done)
+        if exc_type is None:
+            self._send()
+
+    def _send(self) -> None:
+        for name, t_start, t_end, args in self._done:
+            self._tracer.complete(name, t_start, t_end, **args)
+        self._done.clear()
+
+    def step(self, **args) -> None:
+        self._step = _Span("engine.step", time.monotonic(), args)
+
+    def begin(self, name: str, **args) -> None:
+        t = time.monotonic()
+        if self._phase is not None:
+            self._phase.close(t, self._done)
+        self._phase = _Span(name, t, args)
+
+    def args(self, **args) -> None:
+        """Add args to the running phase."""
+        self._phase.args.update(args)
+        self._phase.ann.set_metadata(**args)
+
+    def end(self, t: Optional[float] = None, send: bool = True,
+            **step_args) -> None:
+        """End the running phase, and the step (with ``step_args``); a
+        step's end sends its events and those of the sleeps before it."""
+        t = time.monotonic() if t is None else t
+        if self._phase is not None:
+            self._phase.close(t, self._done)
+            self._phase = None
+        if self._step is not None:
+            self._step.args.update(step_args)
+            if step_args:
+                self._step.ann.set_metadata(**step_args)
+            self._step.close(t, self._done)
+            self._step = None
+            if send:
+                self._send()
+
+
 class DynamicEngine(Engine):
     """Host-scheduled engine over the dynamic page allocator + prefix cache.
 
@@ -997,6 +1085,7 @@ class DynamicEngine(Engine):
         histograms — the raw-list return is kept for compatibility and is
         deprecated in favor of the metrics snapshot (docs/observability.md).
         """
+        t_call = time.monotonic()
         if (self.draft_model is not None) and draft_params is None:
             raise ValueError("speculative engine: serve() needs draft_params")
         prompts_np = np.asarray(prompts, np.int32)
@@ -1076,156 +1165,192 @@ class DynamicEngine(Engine):
                 "serve_step_seconds", "wall time per dynamic-engine step"
             ) if metrics is not None else None
         )
+        # the host loop on the profiler's trace and in the tracer's JSONL,
+        # only while tracing: it calls nothing on the tracer but event() and
+        # complete()
         t0 = time.monotonic()
-
-        while pending or cur is not None or occupied:
-            now = time.monotonic() - t0
-            # idle until the next arrival when nothing is running
-            if (cur is None and not occupied and pending
-                    and arr[pending[0]] > now):
-                time.sleep(min(arr[pending[0]] - now, 2e-3))
-                continue
-            # ---- start a new admission (at most one in flight) ----
-            if (cur is None and pending and free
-                    and arr[pending[0]] <= now):
-                req = pending[0]
-                plen = int(lens_np[req])
-                prompt = [int(x) for x in prompts_np[req, :plen]]
-                slot = min(free)
-                adm = self.blocks.try_admit(
-                    slot, prompt, align_pages=self._align
-                )
-                if adm is None:
-                    # head-of-line wait: retirements will free pages
-                    if not occupied:
-                        raise RuntimeError(
-                            f"admission stalled: request {req} needs pages "
-                            "but no live request will ever free any"
-                        )
-                else:
-                    pending.pop(0)
-                    free.remove(slot)
-                    self._gtab[slot, :] = adm.table_row
-                    if self._wtab is not None:
-                        self._wtab[slot, :] = adm.wtab_row
-                    c = adm.cached_len
-                    prefill_cached += c
-                    prefill_total += plen
-                    if C:
-                        chunks = [
-                            (s0, min(C, plen - s0))
-                            for s0 in range(c, plen, C)
-                        ]
-                    elif c:
-                        chunks = [(c, plen - c)]   # one suffix chunk
-                    else:
-                        chunks = None              # one-shot prefill path
-                    cur = {"req": req, "slot": slot, "plen": plen,
-                           "prompt": prompt, "chunks": chunks, "i": 0,
-                           "adm": adm}
-                    if tracer is not None:
-                        tracer.event(
-                            "admission", req=req, slot=slot, plen=plen,
-                            cached=c, chunks=len(chunks) if chunks else 0,
-                        )
-            # ---- this step's control block ----
-            ctrl = self._ctrl0()
-            finishing = None
-            if cur is not None:
-                ctrl["slot"] = np.int32(cur["slot"])
-                ctrl["req"] = np.int32(cur["req"])
-                ctrl["plen"] = np.int32(cur["plen"])
-                if cur["chunks"] is None:
-                    ctrl["admit_full"] = np.bool_(True)
-                    finishing, cur = cur, None
-                else:
-                    s0, l0 = cur["chunks"][cur["i"]]
-                    ctrl["admit_chunk"] = np.bool_(True)
-                    ctrl["chunk_start"] = np.int32(s0)
-                    ctrl["chunk_len"] = np.int32(l0)
-                    if cur["i"] == 0:
-                        adm = cur["adm"]
-                        n = len(adm.fresh_pages)
-                        ctrl["inval_g"][:n] = adm.fresh_pages
-                        if "inval_w" in ctrl and adm.fresh_wpages:
-                            ctrl["inval_w"][:len(adm.fresh_wpages)] = (
-                                adm.fresh_wpages
+        spans = None
+        if tracer is not None:
+            # setup_us: from the serve() call to t0; tracer_us: the
+            # tracer's clock at t0, which places its JSONL on the trace
+            args = {"requests": R, "slots": S,
+                    "setup_us": (t0 - t_call) * 1e6}
+            if getattr(tracer, "t0", None) is not None:
+                args["tracer_us"] = (t0 - tracer.t0) * 1e6
+            spans = _LoopSpans(tracer, t0, **args)
+        with spans or contextlib.nullcontext():
+            while pending or cur is not None or occupied:
+                now = time.monotonic() - t0
+                # idle until the next arrival when nothing is running
+                if (cur is None and not occupied and pending
+                        and arr[pending[0]] > now):
+                    if spans is not None:
+                        spans.begin("engine.wait_arrival")
+                    time.sleep(min(arr[pending[0]] - now, 2e-3))
+                    if spans is not None:
+                        spans.end()
+                    continue
+                if spans is not None:
+                    spans.step(step=steps)
+                # ---- start a new admission (at most one in flight) ----
+                if (cur is None and pending and free
+                        and arr[pending[0]] <= now):
+                    req = pending[0]
+                    if spans is not None:
+                        spans.begin("engine.admit", req=req)
+                    plen = int(lens_np[req])
+                    prompt = [int(x) for x in prompts_np[req, :plen]]
+                    slot = min(free)
+                    adm = self.blocks.try_admit(
+                        slot, prompt, align_pages=self._align
+                    )
+                    if adm is None:
+                        # head-of-line wait: retirements will free pages
+                        if not occupied:
+                            raise RuntimeError(
+                                f"admission stalled: request {req} needs "
+                                "pages but no live request will ever free any"
                             )
-                    if cur["i"] == len(cur["chunks"]) - 1:
-                        ctrl["chunk_last"] = np.bool_(True)
+                    else:
+                        pending.pop(0)
+                        free.remove(slot)
+                        self._gtab[slot, :] = adm.table_row
+                        if self._wtab is not None:
+                            self._wtab[slot, :] = adm.wtab_row
+                        c = adm.cached_len
+                        prefill_cached += c
+                        prefill_total += plen
+                        if C:
+                            chunks = [
+                                (s0, min(C, plen - s0))
+                                for s0 in range(c, plen, C)
+                            ]
+                        elif c:
+                            chunks = [(c, plen - c)]   # one suffix chunk
+                        else:
+                            chunks = None              # one-shot prefill path
+                        cur = {"req": req, "slot": slot, "plen": plen,
+                               "prompt": prompt, "chunks": chunks, "i": 0,
+                               "adm": adm}
+                        if tracer is not None:
+                            spans.args(cached=c)
+                            tracer.event(
+                                "admission", req=req, slot=slot, plen=plen,
+                                cached=c, chunks=len(chunks) if chunks else 0,
+                            )
+                # ---- this step's control block ----
+                if spans is not None:
+                    spans.begin("engine.prepare")
+                ctrl = self._ctrl0()
+                finishing = None
+                if cur is not None:
+                    ctrl["slot"] = np.int32(cur["slot"])
+                    ctrl["req"] = np.int32(cur["req"])
+                    ctrl["plen"] = np.int32(cur["plen"])
+                    if cur["chunks"] is None:
+                        ctrl["admit_full"] = np.bool_(True)
                         finishing, cur = cur, None
                     else:
-                        cur["i"] += 1
-            if adaptive:
-                ctrl["draft_k"] = k_cur.copy()
-            tables = {"g": jnp.asarray(self._gtab)}
-            if self._wtab is not None:
-                tables["w"] = jnp.asarray(self._wtab)
-            t_step = time.monotonic()
-            with self._sharding_ctx():
-                st, info = self._step(
-                    params, draft_params, st, queue, tables, ctrl
-                )
-            # the device_get syncs, so the span/histogram cover the
-            # device work of this step, not just its dispatch
-            info = jax.device_get(info)
-            steps += 1
-            t_done = time.monotonic()
-            tnow = t_done - t0
-            if tracer is not None:
-                if ctrl["admit_full"]:
-                    phase = "prefill"
-                elif ctrl["admit_chunk"]:
-                    phase = "chunk_prefill"
-                elif self.draft_model is not None:
-                    phase = "verify"
-                else:
-                    phase = "decode"
-                # complete(), not span(): this loop runs once per generated
-                # token, and the contextmanager protocol costs real µs here
-                tracer.complete("step", t_step, t_done, phase=phase)
-            if step_hist is not None:
-                step_hist.observe(t_done - t_step)
-            # ---- host bookkeeping ----
-            if finishing is not None:
-                # prompt fully resident: publish its full pages to the
-                # radix tree before any chance of retirement
-                self.blocks.complete(finishing["slot"], finishing["prompt"])
-                occupied[finishing["slot"]] = finishing["req"]
-            new_len = np.asarray(info["out_len"], np.int64)
-            for r in np.nonzero(new_len > prev_len)[0]:
-                token_times[r].extend(
-                    [tnow] * int(new_len[r] - prev_len[r])
-                )
-            prev_len = new_len
-            if adaptive:
-                # EMA of the per-slot acceptance rate steers k: confident
-                # drafters earn longer chains, struggling ones shorter —
-                # speculation stays profitable per slot, not on average
-                la = np.asarray(info["last_acc"], np.int64)
-                lp = np.asarray(info["last_prop"], np.int64)
-                stepped = lp > 0
-                rate = la[stepped] / lp[stepped]
-                acc_ema[stepped] = 0.8 * acc_ema[stepped] + 0.2 * rate
-                grow = stepped & (acc_ema > 0.8)
-                shrink = stepped & (acc_ema < 0.4)
-                k_cur[grow] = np.minimum(k_cur[grow] + 1, dk0)
-                k_cur[shrink] = np.maximum(k_cur[shrink] - 1, 1)
-            for slot in sorted(occupied):
-                if not bool(info["active"][slot]):
-                    if tracer is not None:
-                        tracer.event("retire", slot=slot, req=occupied[slot])
-                    self.blocks.retire(slot)
-                    del occupied[slot]
-                    free.append(slot)
-                    if adaptive:     # next occupant starts from scratch
-                        k_cur[slot] = dk0
-                        acc_ema[slot] = 0.5
-            if steps > max_steps:
-                raise RuntimeError(
-                    f"dynamic engine exceeded {max_steps} steps — "
-                    "host scheduler bug"
-                )
+                        s0, l0 = cur["chunks"][cur["i"]]
+                        ctrl["admit_chunk"] = np.bool_(True)
+                        ctrl["chunk_start"] = np.int32(s0)
+                        ctrl["chunk_len"] = np.int32(l0)
+                        if cur["i"] == 0:
+                            adm = cur["adm"]
+                            n = len(adm.fresh_pages)
+                            ctrl["inval_g"][:n] = adm.fresh_pages
+                            if "inval_w" in ctrl and adm.fresh_wpages:
+                                ctrl["inval_w"][:len(adm.fresh_wpages)] = (
+                                    adm.fresh_wpages
+                                )
+                        if cur["i"] == len(cur["chunks"]) - 1:
+                            ctrl["chunk_last"] = np.bool_(True)
+                            finishing, cur = cur, None
+                        else:
+                            cur["i"] += 1
+                if adaptive:
+                    ctrl["draft_k"] = k_cur.copy()
+                tables = {"g": jnp.asarray(self._gtab)}
+                if self._wtab is not None:
+                    tables["w"] = jnp.asarray(self._wtab)
+                t_step = time.monotonic()
+                if spans is not None:
+                    spans.begin("engine.dispatch")
+                with self._sharding_ctx():
+                    st, info = self._step(
+                        params, draft_params, st, queue, tables, ctrl
+                    )
+                if spans is not None:
+                    spans.begin("engine.sync")
+                # the device_get syncs, so the span/histogram cover the
+                # device work of this step, not just its dispatch
+                info = jax.device_get(info)
+                steps += 1
+                t_done = time.monotonic()
+                tnow = t_done - t0
+                if tracer is not None:
+                    spans.begin("engine.bookkeep")
+                    if ctrl["admit_full"]:
+                        phase, prompt_len = "prefill", int(ctrl["plen"])
+                    elif ctrl["admit_chunk"]:
+                        phase = "chunk_prefill"
+                        prompt_len = int(ctrl["chunk_len"])
+                    else:
+                        phase = ("verify" if self.draft_model is not None
+                                 else "decode")
+                        prompt_len = 0
+                    # slots holding a request, the one admitted here too
+                    live = len(occupied) + (prompt_len > 0)
+                    # complete(), not span(): this loop runs once per
+                    # generated token, and the contextmanager protocol costs
+                    # real µs here
+                    tracer.complete("step", t_step, t_done, phase=phase)
+                if step_hist is not None:
+                    step_hist.observe(t_done - t_step)
+                # ---- host bookkeeping ----
+                if finishing is not None:
+                    # prompt fully resident: publish its full pages to the
+                    # radix tree before any chance of retirement
+                    self.blocks.complete(finishing["slot"],
+                                         finishing["prompt"])
+                    occupied[finishing["slot"]] = finishing["req"]
+                new_len = np.asarray(info["out_len"], np.int64)
+                for r in np.nonzero(new_len > prev_len)[0]:
+                    token_times[r].extend(
+                        [tnow] * int(new_len[r] - prev_len[r])
+                    )
+                prev_len = new_len
+                if adaptive:
+                    # EMA of the per-slot acceptance rate steers k: confident
+                    # drafters earn longer chains, struggling ones shorter —
+                    # speculation stays profitable per slot, not on average
+                    la = np.asarray(info["last_acc"], np.int64)
+                    lp = np.asarray(info["last_prop"], np.int64)
+                    stepped = lp > 0
+                    rate = la[stepped] / lp[stepped]
+                    acc_ema[stepped] = 0.8 * acc_ema[stepped] + 0.2 * rate
+                    grow = stepped & (acc_ema > 0.8)
+                    shrink = stepped & (acc_ema < 0.4)
+                    k_cur[grow] = np.minimum(k_cur[grow] + 1, dk0)
+                    k_cur[shrink] = np.maximum(k_cur[shrink] - 1, 1)
+                for slot in sorted(occupied):
+                    if not bool(info["active"][slot]):
+                        if tracer is not None:
+                            tracer.event("retire", slot=slot,
+                                         req=occupied[slot])
+                        self.blocks.retire(slot)
+                        del occupied[slot]
+                        free.append(slot)
+                        if adaptive:     # next occupant starts from scratch
+                            k_cur[slot] = dk0
+                            acc_ema[slot] = 0.5
+                if spans is not None:
+                    spans.end(phase=phase, live=live, chunk_len=prompt_len)
+                if steps > max_steps:
+                    raise RuntimeError(
+                        f"dynamic engine exceeded {max_steps} steps — "
+                        "host scheduler bug"
+                    )
 
         # pools stay warm: the next serve() hits prefixes cached by this one
         self._pools = st["pools"]

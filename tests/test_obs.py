@@ -35,7 +35,6 @@ from repro.obs import (
     parse_prometheus,
     percentile_summary,
 )
-from repro.obs.trace import PHASE_KERNELS
 from repro.optim.optimizer import Optimizer
 from repro.serving.engine import DynamicEngine, Engine, EngineConfig
 
@@ -215,8 +214,10 @@ def test_tracer_span_event_schema(tmp_path):
     ev, sp = tr.events
     assert ev["ph"] == "i" and ev["args"] == {"req": 0, "slot": 1}
     assert sp["ph"] == "X" and sp["dur"] >= 0 and sp["ts"] >= ev["ts"]
-    # phases the roofline profiles carry their dominating kernel names
-    assert sp["args"]["kernel"] == PHASE_KERNELS["decode"]
+    # the schema: exactly these keys, args exactly as given
+    assert set(ev) == {"name", "ph", "ts", "args"}
+    assert set(sp) == {"name", "ph", "ts", "dur", "args"}
+    assert sp["args"] == {"phase": "decode"}
     path = str(tmp_path / "trace.jsonl")
     assert tr.dump(path) == 2
     assert load_jsonl(path) == tr.events
@@ -230,7 +231,8 @@ def test_tracer_complete_matches_span_schema():
     assert ev["ph"] == "X"
     np.testing.assert_allclose(ev["ts"], 1e3, rtol=1e-6)
     np.testing.assert_allclose(ev["dur"], 2e3, rtol=1e-6)
-    assert ev["args"]["kernel"] == PHASE_KERNELS["verify"]
+    assert ev == {"name": "step", "ph": "X", "ts": ev["ts"],
+                  "dur": ev["dur"], "args": {"phase": "verify"}}
 
 
 def test_tracer_bounded():
@@ -428,6 +430,10 @@ def test_train_loop_with_obs():
     assert len(obs.ring) == 3                 # telemetry drained every step
     spans = [e for e in obs.tracer.events if e["name"] == "train_step"]
     assert len(spans) == 3
+    data = [e for e in obs.tracer.events if e["name"] == "train.data"]
+    assert [e["args"]["step"] for e in data] == [0, 1, 2]
+    for d, st in zip(data, spans):          # the batch, then its step
+        assert d["ts"] + d["dur"] <= st["ts"]
     parse_prometheus(obs.metrics.to_prometheus())   # exposition well-formed
 
 
@@ -530,6 +536,187 @@ def test_zero_recompile_with_obs(variant, serve_m, proxy_m):
             assert "verify" in phases
             if fams.get("spec_drafts_proposed_total", 0):
                 assert "spec_acceptance_rate" in fams
+
+
+# ---------------------------------------------------------------------------
+# the profiler's trace: host spans on the device's clock
+# ---------------------------------------------------------------------------
+
+_ENGINE_PHASES = ("engine.admit", "engine.prepare", "engine.dispatch",
+                  "engine.sync", "engine.bookkeep")
+
+
+def _host_spans(logdir, prefix):
+    """(name, start_ns, end_ns, args) of the host events named ``prefix*``
+    in the one profiler trace under ``logdir``, in time order."""
+    from jax.profiler import ProfileData
+
+    (path,) = logdir.glob("plugins/profile/*/*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    out = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+           for plane in pd.planes if plane.name.startswith("/host:")
+           for line in plane.lines for e in line.events
+           if e.name.startswith(prefix)]
+    return sorted(out, key=lambda s: (s[1], -s[2]))
+
+
+def test_tracer_span_lands_on_profiler_trace(tmp_path):
+    tr = Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("train_step", phase="train_step", step=3):
+            pass
+        tr.complete("step", tr.t0, tr.t0 + 1e-3, phase="decode")
+    ((name, a, b, args),) = _host_spans(tmp_path, "train_step")
+    assert args == {"phase": "train_step", "step": 3} and b >= a
+    # complete() records an interval already over: JSONL only
+    assert _host_spans(tmp_path, "step") == []
+    assert [e["name"] for e in tr.events] == ["train_step", "step"]
+
+
+def test_dynamic_engine_phases_on_profiler_trace(serve_m, tmp_path):
+    """A traced DynamicEngine serve puts its host loop on the profiler's
+    trace: one engine.step per dispatched step, its phases back to back
+    inside it, with the args the bench's metrics read."""
+    cfg, model, params = serve_m
+    prompts, lens = _prompts(cfg, R=4, L=16)
+    ecfg = EngineConfig(**_ECFG, prefix_cache=True, prefill_chunk=4)
+    plain, instr, obs = _engine_pair(model, ecfg, DynamicEngine)
+    ref = plain.serve(params, prompts, lens)
+    instr.serve(params, prompts, lens)        # compiles outside the trace
+    obs.tracer.events.clear()
+    arrivals = [0.0, 0.0, 0.3, 0.3]          # the engine idles in between
+    with jax.profiler.trace(str(tmp_path)):
+        out = instr.serve(params, prompts, lens, arrivals=arrivals)
+    assert np.array_equal(np.asarray(out["tokens"]),
+                          np.asarray(ref["tokens"]))
+    assert instr.compile_count() == 1
+    spans = _host_spans(tmp_path, "engine.")
+    ((_, s0, s1, serve_args),) = [s for s in spans if s[0] == "engine.serve"]
+    assert serve_args["requests"] == 4 and serve_args["slots"] == 2
+    # the tracer's clock at the loop's start, before its first step event
+    first = next(e for e in obs.tracer.events if e["name"] == "step")
+    assert 0 <= serve_args["tracer_us"] <= first["ts"]
+    steps = [s for s in spans if s[0] == "engine.step"]
+    assert len(steps) == int(out["steps"])
+    assert [s[3]["step"] for s in steps] == list(range(len(steps)))
+    jsonl = [e["args"]["phase"] for e in obs.tracer.events
+             if e["name"] == "step"]
+    assert [s[3]["phase"] for s in steps] == jsonl
+    assert {"chunk_prefill", "decode"} <= set(jsonl)
+    for _, a, b, args in steps:
+        assert s0 <= a <= b <= s1
+        assert 1 <= args["live"] <= 2
+        assert (args["chunk_len"] > 0) == (args["phase"] == "chunk_prefill")
+        kids = [s for s in spans if s[0] in _ENGINE_PHASES
+                and a <= s[1] and s[2] <= b]
+        names = [k[0] for k in kids]
+        assert names in (list(_ENGINE_PHASES), list(_ENGINE_PHASES[1:]))
+        for k0, k1 in zip(kids, kids[1:]):
+            assert k0[2] <= k1[1], "phases overlap"
+    admits = [s for s in spans if s[0] == "engine.admit"]
+    assert sorted(s[3]["req"] for s in admits) == [0, 1, 2, 3]
+    assert all("cached" in s[3] for s in admits)
+    # every phase sits in a step; waits sit between steps
+    inside = sum(1 for s in spans if s[0] in _ENGINE_PHASES)
+    assert inside == sum(len([k for k in spans if k[0] in _ENGINE_PHASES
+                              and a <= k[1] and k[2] <= b])
+                         for _, a, b, _ in steps)
+    waits = [s for s in spans if s[0] == "engine.wait_arrival"]
+    assert waits
+    for _, a, b, _ in waits:
+        assert not any(sa < b and a < sb for _, sa, sb, _ in steps)
+    # the JSONL holds the same spans, with the same args, as complete events
+    ev = [e for e in obs.tracer.events if e["name"].startswith("engine.")]
+    assert all(e["ph"] == "X" for e in ev)
+    assert sorted(repr((e["name"], e.get("args", {}))) for e in ev) == sorted(
+        repr((n, args)) for n, _, _, args in spans)
+    (jserve,) = [e for e in ev if e["name"] == "engine.serve"]
+    assert jserve["ts"] == serve_args["tracer_us"]
+    assert serve_args["setup_us"] > 0
+    # on the tracer's clock too, a step's phases fill it back to back
+    for st in (e for e in ev if e["name"] == "engine.step"):
+        kids = sorted((e for e in ev if e["name"] in _ENGINE_PHASES
+                       and st["ts"] <= e["ts"] < st["ts"] + st["dur"]),
+                      key=lambda e: e["ts"])
+        assert [k["name"] for k in kids][-4:] == list(_ENGINE_PHASES[1:])
+        for k0, k1 in zip(kids, kids[1:]):
+            assert k0["ts"] + k0["dur"] == pytest.approx(k1["ts"], abs=1e-3)
+        assert kids[-1]["ts"] + kids[-1]["dur"] == pytest.approx(
+            st["ts"] + st["dur"], abs=1e-3)
+
+
+class _EventCompleteOnly:
+    """A tracer with nothing but event() and complete(), as a stand-in
+    tracer may be."""
+
+    def __init__(self):
+        self.calls = []
+
+    def event(self, name, **args):
+        self.calls.append(("event", name))
+
+    def complete(self, name, t_start, t_end, **args):
+        self.calls.append(("complete", name))
+
+
+class _FirstStepDone(Exception):
+    pass
+
+
+class _StopAtFirstComplete(_EventCompleteOnly):
+    """A stand-in whose complete() ends the serve, as a warm-up's may."""
+
+    def complete(self, name, t_start, t_end, **args):
+        super().complete(name, t_start, t_end, **args)
+        raise _FirstStepDone
+
+
+def test_dynamic_engine_stops_after_first_step(serve_m):
+    """The engine's first complete() is the first step's ``step`` event,
+    sent once the step has run: a tracer that stops the serve there stops
+    it after one compiled step, with no engine.* event sent before it."""
+    cfg, model, params = serve_m
+    prompts, lens = _prompts(cfg, R=3, L=16)
+    tracer = _StopAtFirstComplete()
+    eng = DynamicEngine(model, EngineConfig(**_ECFG, prefill_chunk=4),
+                        obs=ServeObs(tracer=tracer))
+    with pytest.raises(_FirstStepDone):
+        eng.serve(params, prompts, lens)
+    assert eng.compile_count() == 1
+    assert [c for c in tracer.calls if c[0] == "complete"] == [
+        ("complete", "step")]
+
+
+@pytest.mark.parametrize("mode", ["obs_none", "event_complete_only"])
+def test_dynamic_engine_tracer_contract(mode, serve_m, monkeypatch):
+    """With obs=None the engine makes no profiler call; with a tracer it
+    calls nothing on it but event() and complete().  One compile either
+    way, tokens unchanged."""
+    import repro.serving.engine as engine_mod
+
+    cfg, model, params = serve_m
+    prompts, lens = _prompts(cfg, R=3, L=16)
+    ecfg = EngineConfig(**_ECFG, prefill_chunk=4)
+    ref = DynamicEngine(model, ecfg).serve(params, prompts, lens)
+    opened = []
+    real = engine_mod.annotate
+    monkeypatch.setattr(engine_mod, "annotate",
+                        lambda name, **a: opened.append(name) or real(name, **a))
+    tracer = _EventCompleteOnly() if mode != "obs_none" else None
+    obs = ServeObs(tracer=tracer) if tracer is not None else None
+    eng = DynamicEngine(model, ecfg, obs=obs)
+    out = eng.serve(params, prompts, lens)
+    assert np.array_equal(np.asarray(out["tokens"]), np.asarray(ref["tokens"]))
+    assert eng.compile_count() == 1
+    if tracer is None:
+        assert opened == []
+    else:
+        steps = int(out["steps"])
+        assert opened.count("engine.step") == steps
+        assert tracer.calls.count(("complete", "step")) == steps
+        assert tracer.calls.count(("complete", "engine.step")) == steps
+        assert tracer.calls.count(("complete", "engine.serve")) == 1
+        assert {c for c, _ in tracer.calls} == {"event", "complete"}
 
 
 def test_dynamic_record_times_with_obs(serve_m):
