@@ -104,9 +104,11 @@ def ref_kernels():
 
 
 def _kernels_ran(ops) -> dict:
-    """Snapshot of the impls the ops resolved to; all must be pallas."""
+    """Snapshot of the impls the ops resolved to (and the attention tile
+    plan); every impl must be pallas."""
     got = dict(ops.RESOLVED)
-    bad = {k: v for k, v in got.items() if v != "pallas"}
+    bad = {k: v for k, v in got.items()
+           if k != "attention_tiles" and v != "pallas"}
     check(bool(got) and not bad,
           f"kernel ops did not resolve to 'pallas': {got}")
     return got

@@ -41,6 +41,10 @@ class SyntheticLM:
         ranks = np.arange(1, V + 1, dtype=np.float64)
         self.unigram = ranks ** (-cfg.zipf_a)
         self.unigram /= self.unigram.sum()
+        # the cumulative table RandomState.choice(V, p=unigram) builds on
+        # every call, built once: ``_unigram_draw`` is that draw, bit for bit
+        self._cdf = self.unigram.cumsum()
+        self._cdf /= self._cdf[-1]
         # planted successor table: token v -> k preferred successors
         self.successors = rng.randint(0, V, size=(V, cfg.markov_k)).astype(np.int32)
 
@@ -57,23 +61,27 @@ class SyntheticLM:
         )
         # draw the whole global batch, slice this host's rows => identical
         # global data regardless of host layout (elastic-restart safe)
-        V = cfg.vocab_size
         B, S = cfg.global_batch, cfg.seq_len
         toks = np.empty((B, S + 1), np.int32)
-        toks[:, 0] = rng.choice(V, size=B, p=self.unigram)
+        toks[:, 0] = self._unigram_draw(rng, B)
         for t in range(S):
             prev = toks[:, t]
             use_markov = rng.random_sample(B) < cfg.markov_p
             succ_pick = self.successors[
                 prev, rng.randint(0, cfg.markov_k, size=B)
             ]
-            indep = rng.choice(V, size=B, p=self.unigram)
+            indep = self._unigram_draw(rng, B)
             toks[:, t + 1] = np.where(use_markov, succ_pick, indep)
         rows = slice(host_id * per_host, (host_id + 1) * per_host)
         return {
             "tokens": toks[rows, :-1],
             "labels": toks[rows, 1:].astype(np.int32),
         }
+
+    def _unigram_draw(self, rng: np.random.RandomState, n: int) -> np.ndarray:
+        """``rng.choice(V, size=n, p=self.unigram)``: the same uniforms drawn
+        and looked up in the same table, without rebuilding the table."""
+        return self._cdf.searchsorted(rng.random_sample(n), side="right")
 
     def batches(
         self, start_step: int = 0, host_id: int = 0, host_count: int = 1

@@ -3,13 +3,24 @@
 TPU-native design (not a CUDA port):
   - HBM -> VMEM tiling via BlockSpec: q tile (bq, d_head), k/v tiles
     (bk, d_head); the MXU sees (bq x d) @ (d x bk) and (bq x bk) @ (bk x d)
-    matmuls — pick bq = bk = 128 multiples for systolic-array alignment.
+    matmuls.  Tiles come from the shapes (``choose_tiles``): the largest
+    power-of-two multiple of 128 that divides the sequence, up to a cap
+    that fits VMEM; with a sliding window, bk no wider than the window
+    needs.  Each grid step costs a fixed fraction of a microsecond whatever
+    it computes, so wide tiles mean few steps.
   - online softmax with running (m, l, acc) carried in VMEM scratch across
     the kv grid dimension (TPU grids iterate the last dim sequentially, so
     scratch accumulation is well-defined — this replaces the CUDA warp-level
     reduction structure with grid-sequential accumulation).
   - causal + sliding-window masking by block skipping (pl.when) plus an
-    intra-block iota mask; fully-masked kv blocks are never computed.
+    intra-block iota mask; fully-masked tile pairs are never computed, and
+    in the dk/dv kernel never fetched either: its q/do/lse/delta index maps
+    clamp an invisible q tile's block index to the nearest visible one, and
+    Pallas skips the copy of a block index that does not change between
+    grid steps.  The forward and dq kernels keep plain k/v maps, and every
+    computed causal or windowed tile pair builds its mask: clamped k/v maps
+    there, and a mask-free path for wholly visible pairs, measured no
+    faster on a v5e.
   - gemma2 attention-logit softcap and muP 1/d scaling folded in (scale is
     an argument — Definition 4.1 is a compile-time constant here).
   - GQA: the kv-head block index is derived from the q-head grid index.
@@ -62,22 +73,79 @@ def _block_visible(q_start, k_start, bq, bk, causal: bool, window: int):
     return needed
 
 
-def _tile_mask(q_start, k_start, bq, bk, seq_len, causal: bool, window: int):
-    """(bq, bk) bool visibility mask for one tile pair."""
+def _tile_mask(q_start, k_start, bq, bk, causal: bool, window: int):
+    """(bq, bk) bool visibility mask for one tile pair, causal or windowed
+    (T is a multiple of bk, so no key lies past the sequence; attention
+    that is neither needs no mask)."""
     q_idx = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     k_idx = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = k_idx < seq_len
-    if causal:
-        mask &= k_idx <= q_idx
-    if window:
-        mask &= (q_idx - k_idx) < window
+    dist = q_idx - k_idx
+    mask = dist >= 0 if causal else dist < window
+    if causal and window:
+        mask &= dist < window
     return mask
+
+
+def _q_block(ki, qi, *, bq, bk, nq, causal, window):
+    """The q/do/lse/delta block index dk/dv grid step (ki, qi) fetches: qi
+    clamped to the q tiles that see k tile ki, so a step with nothing
+    visible names a block Pallas already holds, and no copy is made."""
+    if causal:
+        qi = jnp.maximum(qi, jnp.minimum((ki * bk) // bq, nq - 1))
+    if window:
+        qi = jnp.minimum(qi, (ki * bk + bk + window - 2) // bq)
+    return qi
+
+
+def tile_plan(S, T, bq, bk, *, causal: bool, window: int):
+    """(computed, total) tile pairs of one (batch, head): those the kernels
+    compute, and all."""
+    computed = sum(
+        bool(_block_visible(q_start, k_start, bq, bk, causal, window))
+        for q_start in range(0, S, bq) for k_start in range(0, T, bk))
+    return computed, (S // bq) * (T // bk)
+
+
+# Tile caps, from a sweep of the kernels alone on a v5e at SmolLM-360M's
+# training shapes (benchmarks/flash_tiles.py; PERF.md).  512 x 512 fits
+# Mosaic's default scoped VMEM in all three kernels at every head width up
+# to 256, f32 or bf16, so the caps depend on neither.
+MAX_BLOCK_Q = 512
+MAX_BLOCK_K = 512
+
+
+def _pow2_tile(n: int, cap: int) -> int:
+    """The largest power-of-two multiple of 128 that divides n, at most cap;
+    min(128, n) where 128 does not divide n (a short sequence is one tile,
+    and an untileable one is left for the caller to refuse)."""
+    if n % 128:
+        return min(128, n)
+    t = 128
+    while t * 2 <= cap and n % (t * 2) == 0:
+        t *= 2
+    return t
+
+
+def choose_tiles(S, T, *, window, block_q=None, block_k=None):
+    """(bq, bk) for S queries over T keys: an explicit block, clipped to its
+    sequence, else the shape rule — tiles as wide as divide the sequence,
+    because each grid step costs a fixed time whatever it computes; under
+    a sliding window bk no wider than the smallest power-of-two multiple of
+    128 that holds the window, so few columns beyond it are fetched."""
+    if block_q is None:
+        block_q = _pow2_tile(S, MAX_BLOCK_Q)
+    if block_k is None:
+        cap = MAX_BLOCK_K
+        if window:
+            cap = min(cap, max(128, 1 << (window - 1).bit_length()))
+        block_k = _pow2_tile(T, cap)
+    return min(block_q, S), min(block_k, T)
 
 
 def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     *, scale: float, causal: bool, window: int, softcap: float,
-    bq: int, bk: int, nk: int, seq_len: int, policy=None,
+    bq: int, bk: int, nk: int, policy=None,
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -103,8 +171,9 @@ def _flash_kernel(
         s = kernel_dot(q, k.T, policy) * scale
         if softcap:
             s = softcap * jnp.tanh(s / softcap)
-        mask = _tile_mask(q_start, k_start, bq, bk, seq_len, causal, window)
-        s = jnp.where(mask, s, NEG_INF)
+        if causal or window:
+            mask = _tile_mask(q_start, k_start, bq, bk, causal, window)
+            s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_ref[...]                                 # (bq, 1)
         l_prev = l_ref[...]
@@ -128,7 +197,7 @@ def _flash_kernel(
 
 def _recompute_p_ds(
     q, k, v, do, lse_row, delta_row, q_start, k_start,
-    *, scale, causal, window, softcap, bq, bk, seq_len, policy=None,
+    *, scale, causal, window, softcap, bq, bk, policy=None,
 ):
     """Shared backward tile math: recompute p and ds = dL/d(pre-cap logits).
 
@@ -141,11 +210,13 @@ def _recompute_p_ds(
     if softcap:
         t = jnp.tanh(s / softcap)
         s = softcap * t
-    mask = _tile_mask(q_start, k_start, bq, bk, seq_len, causal, window)
-    # p is exactly the forward softmax: exp(masked logits - lse); masked
-    # entries are exp(NEG_INF - lse) = 0, written explicitly to avoid
-    # overflow paths.
-    p = jnp.where(mask, jnp.exp(s - lse_row), 0.0)
+    p = jnp.exp(s - lse_row)
+    if causal or window:
+        # p is exactly the forward softmax: exp(masked logits - lse); masked
+        # entries are exp(NEG_INF - lse) = 0, written explicitly to avoid
+        # overflow paths.
+        mask = _tile_mask(q_start, k_start, bq, bk, causal, window)
+        p = jnp.where(mask, p, 0.0)
     dp = kernel_dot(do, v.T, policy)
     ds = p * (dp - delta_row)
     if softcap:
@@ -158,7 +229,7 @@ def _recompute_p_ds(
 def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref,
     *, scale: float, causal: bool, window: int, softcap: float,
-    bq: int, bk: int, nk: int, seq_len: int, policy=None,
+    bq: int, bk: int, nk: int, policy=None,
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -182,7 +253,7 @@ def _flash_bwd_dq_kernel(
         _, ds = _recompute_p_ds(
             q, k, v, do, lse_row, delta_row, q_start, k_start,
             scale=scale, causal=causal, window=window, softcap=softcap,
-            bq=bq, bk=bk, seq_len=seq_len, policy=policy,
+            bq=bq, bk=bk, policy=policy,
         )
         acc_ref[...] += kernel_dot(ds, k, policy)
 
@@ -195,7 +266,7 @@ def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_acc_ref, dv_acc_ref,
     *, scale: float, causal: bool, window: int, softcap: float,
-    bq: int, bk: int, nq: int, n_group: int, seq_len: int, policy=None,
+    bq: int, bk: int, nq: int, n_group: int, policy=None,
 ):
     ki = pl.program_id(2)
     gi = pl.program_id(3)
@@ -221,7 +292,7 @@ def _flash_bwd_dkv_kernel(
         p, ds = _recompute_p_ds(
             q, k, v, do, lse_row, delta_row, q_start, k_start,
             scale=scale, causal=causal, window=window, softcap=softcap,
-            bq=bq, bk=bk, seq_len=seq_len, policy=policy,
+            bq=bq, bk=bk, policy=policy,
         )
         dv_acc_ref[...] += kernel_dot(p.T, do, policy)
         dk_acc_ref[...] += kernel_dot(ds.T, q, policy)
@@ -250,7 +321,7 @@ def _fwd_call(q, k, v, *, scale, causal, window, softcap, bq, bk, interpret,
     kernel = functools.partial(
         _flash_kernel,
         scale=scale, causal=causal, window=window, softcap=softcap,
-        bq=bq, bk=bk, nk=nk, seq_len=T, policy=policy,
+        bq=bq, bk=bk, nk=nk, policy=policy,
     )
     return pl.pallas_call(
         kernel,
@@ -288,7 +359,7 @@ def _bwd_dq_call(
     kernel = functools.partial(
         _flash_bwd_dq_kernel,
         scale=scale, causal=causal, window=window, softcap=softcap,
-        bq=bq, bk=bk, nk=nk, seq_len=T, policy=policy,
+        bq=bq, bk=bk, nk=nk, policy=policy,
     )
     q_spec = pl.BlockSpec((1, 1, bq, d), lambda b, h, qi, ki: (b, h, qi, 0))
     kv_spec = pl.BlockSpec(
@@ -317,16 +388,20 @@ def _bwd_dkv_call(
     kernel = functools.partial(
         _flash_bwd_dkv_kernel,
         scale=scale, causal=causal, window=window, softcap=softcap,
-        bq=bq, bk=bk, nq=nq, n_group=G, seq_len=T, policy=policy,
+        bq=bq, bk=bk, nq=nq, n_group=G, policy=policy,
     )
+    q_block = functools.partial(
+        _q_block, bq=bq, bk=bk, nq=nq, causal=causal, window=window)
     q_spec = pl.BlockSpec(
-        (1, 1, bq, d), lambda b, kh, ki, g, qi: (b, kh * G + g, qi, 0)
+        (1, 1, bq, d),
+        lambda b, kh, ki, g, qi: (b, kh * G + g, q_block(ki, qi), 0),
     )
     kv_spec = pl.BlockSpec(
         (1, 1, bk, d), lambda b, kh, ki, g, qi: (b, kh, ki, 0)
     )
     row_spec = pl.BlockSpec(
-        (1, 1, bq, 1), lambda b, kh, ki, g, qi: (b, kh * G + g, qi, 0)
+        (1, 1, bq, 1),
+        lambda b, kh, ki, g, qi: (b, kh * G + g, q_block(ki, qi), 0),
     )
     return pl.pallas_call(
         kernel,
@@ -409,13 +484,14 @@ def flash_attention(
     causal: bool = True,
     window: int = 0,
     softcap: float = 0.0,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: int | None = None,
+    block_k: int | None = None,
     interpret: bool = False,
     policy=None,
 ) -> jax.Array:
     """Pallas flash attention, differentiable (custom_vjp backward kernels);
-    shapes must tile (S % block_q == 0 etc. after internal clamping).  Use
+    shapes must tile (S % block_q == 0 etc. after internal clamping).  A
+    block left ``None`` comes from ``choose_tiles``.  Use
     kernels.ops.attention for the auto-fallback wrapper.
 
     ``policy`` routes every tile matmul (q.kT, p.v, and the dq/dk/dv
@@ -424,8 +500,8 @@ def flash_attention(
     B, S, H, d = q.shape
     T, K = k.shape[1], k.shape[2]
     assert H % K == 0
-    bq = min(block_q, S)
-    bk = min(block_k, T)
+    bq, bk = choose_tiles(S, T, window=window, block_q=block_q,
+                          block_k=block_k)
     assert S % bq == 0 and T % bk == 0, (S, T, bq, bk)
     fn = _flash_fn(
         float(scale), bool(causal), int(window), float(softcap),

@@ -32,7 +32,10 @@ autodiff, the kernel paths via the custom_vjp backward kernels in their
 modules (flash_attention.py, rmsnorm.py, cross_entropy.py).
 
 ``RESOLVED`` records, per op, the impl its latest call resolved to (after
-any fallback), so a run can print and check which path actually ran.
+any fallback), so a run can print and check which path actually ran; and,
+under ``attention_tiles``, the tile plan of the latest attention call if it
+ran a kernel: ``"<bq>x<bk> computed <c>/<n>"``, the tile pairs of one
+(batch, head) the kernels compute, out of all of them.
 """
 from __future__ import annotations
 
@@ -256,10 +259,15 @@ def _attention_jit(
 
 def attention(
     q, k, v, *, scale, causal: bool = True, window: int = 0,
-    softcap: float = 0.0, block_q: int = 128, block_k: int = 128,
-    impl: str = "auto", policy=None,
+    softcap: float = 0.0, block_q: int | None = None,
+    block_k: int | None = None, impl: str = "auto", policy=None,
 ):
     """Flash attention with GQA/causal/sliding-window/softcap.
+
+    ``block_q``/``block_k`` left ``None`` take the kernel's shape rule
+    (``flash_attention.choose_tiles``).  The shard_map path splits only
+    batch and heads, so each shard's S and T, and so its tiles, are the
+    whole call's.
 
     ``scale`` may be a traced scalar (the vmap sweep engine threads
     alpha_attn through it): the kernel path folds it into q ahead of the
@@ -273,7 +281,8 @@ def attention(
     requested = impl
     impl = _resolve_impl(impl)
     S, T = q.shape[1], k.shape[1]
-    bq, bk = min(block_q, S), min(block_k, T)
+    bq, bk = fa.choose_tiles(S, T, window=window, block_q=block_q,
+                             block_k=block_k)
     if impl != "ref" and (S % bq or T % bk):
         _reject_untileable(
             "attention", impl, requested,
@@ -283,7 +292,10 @@ def attention(
     if policy is not None and not policy.active:
         policy = None
     RESOLVED["attention"] = impl
+    RESOLVED.pop("attention_tiles", None)
     if impl != "ref":
+        c, n = fa.tile_plan(S, T, bq, bk, causal=causal, window=window)
+        RESOLVED["attention_tiles"] = f"{bq}x{bk} computed {c}/{n}"
         out = _shard_map_attention(
             impl, q, k, v, scale, causal=causal, window=window,
             softcap=softcap, block_q=bq, block_k=bk, policy=policy,

@@ -52,21 +52,28 @@ def _qkvw(B, S, T, H, K, d, dtype, seed=0):
 
 # same config space as tests/test_kernels.py SHAPE_SWEEP
 SHAPE_SWEEP = [
-    # B, S, H, K, d, causal, window, softcap
-    (1, 128, 4, 4, 64, True, 0, 0.0),
-    (2, 128, 4, 2, 64, True, 0, 0.0),       # GQA
-    (2, 256, 8, 1, 32, True, 0, 0.0),       # MQA
-    (1, 256, 4, 2, 64, True, 64, 0.0),      # sliding window
-    (1, 128, 4, 2, 128, True, 0, 50.0),     # gemma2 softcap
-    (1, 256, 2, 2, 64, True, 32, 30.0),     # window + softcap
-    (2, 128, 4, 4, 16, False, 0, 0.0),      # non-causal (encoder)
+    # B, S, H, K, d, causal, window, softcap, block (None: the shape rule)
+    (1, 128, 4, 4, 64, True, 0, 0.0, 64),
+    (2, 128, 4, 2, 64, True, 0, 0.0, 64),       # GQA
+    (2, 256, 8, 1, 32, True, 0, 0.0, 64),       # MQA
+    (1, 256, 4, 2, 64, True, 64, 0.0, 64),      # sliding window
+    (1, 128, 4, 2, 128, True, 0, 50.0, 64),     # gemma2 softcap
+    (1, 256, 2, 2, 64, True, 32, 30.0, 64),     # window + softcap
+    (2, 128, 4, 4, 16, False, 0, 0.0, 64),      # non-causal (encoder)
+    # the shape rule's tiles: 512 x 512 at S = 1024, 512 x 256 under a 192
+    # window (its edge inside a k tile), 128 x 128 at S = 384
+    (1, 1024, 4, 2, 64, True, 0, 0.0, None),    # causal GQA
+    (1, 1024, 2, 1, 64, True, 192, 0.0, None),  # sliding window
+    (1, 1024, 2, 2, 64, True, 0, 30.0, None),   # softcap
+    (1, 1024, 2, 2, 64, False, 0, 0.0, None),   # non-causal
+    (1, 384, 2, 1, 64, True, 0, 0.0, None),     # falls back to 128
 ]
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("case", SHAPE_SWEEP)
 def test_attention_grads_match_oracle(case, dtype):
-    B, S, H, K, d, causal, window, softcap = case
+    B, S, H, K, d, causal, window, softcap, block = case
     q, k, v, w = _qkvw(B, S, S, H, K, d, dtype)
     scale = 1.0 / d  # muP 1/d attention
     wf = w.astype(jnp.float32)
@@ -74,7 +81,7 @@ def test_attention_grads_match_oracle(case, dtype):
     def f_kernel(q, k, v):
         o = ops.attention(
             q, k, v, scale=scale, causal=causal, window=window,
-            softcap=softcap, block_q=64, block_k=64, impl="interpret",
+            softcap=softcap, block_q=block, block_k=block, impl="interpret",
         )
         return jnp.sum(o.astype(jnp.float32) * wf)
 

@@ -29,26 +29,33 @@ def _qkv(B, S, T, H, K, d, dtype, seed=0):
 
 
 SHAPE_SWEEP = [
-    # B, S, H, K, d, causal, window, softcap
-    (1, 128, 4, 4, 64, True, 0, 0.0),
-    (2, 128, 4, 2, 64, True, 0, 0.0),       # GQA
-    (2, 256, 8, 1, 32, True, 0, 0.0),       # MQA
-    (1, 256, 4, 2, 64, True, 64, 0.0),      # sliding window
-    (1, 128, 4, 2, 128, True, 0, 50.0),     # gemma2 softcap
-    (1, 256, 2, 2, 64, True, 32, 30.0),     # window + softcap
-    (2, 128, 4, 4, 16, False, 0, 0.0),      # non-causal (encoder)
+    # B, S, H, K, d, causal, window, softcap, block (None: the shape rule)
+    (1, 128, 4, 4, 64, True, 0, 0.0, 64),
+    (2, 128, 4, 2, 64, True, 0, 0.0, 64),       # GQA
+    (2, 256, 8, 1, 32, True, 0, 0.0, 64),       # MQA
+    (1, 256, 4, 2, 64, True, 64, 0.0, 64),      # sliding window
+    (1, 128, 4, 2, 128, True, 0, 50.0, 64),     # gemma2 softcap
+    (1, 256, 2, 2, 64, True, 32, 30.0, 64),     # window + softcap
+    (2, 128, 4, 4, 16, False, 0, 0.0, 64),      # non-causal (encoder)
+    # the shape rule's tiles: 512 x 512 at S = 1024, 512 x 256 under a 192
+    # window (its edge inside a k tile), 128 x 128 at S = 384
+    (1, 1024, 4, 2, 64, True, 0, 0.0, None),    # causal GQA
+    (1, 1024, 2, 1, 64, True, 192, 0.0, None),  # sliding window
+    (1, 1024, 2, 2, 64, True, 0, 30.0, None),   # softcap
+    (1, 1024, 2, 2, 64, False, 0, 0.0, None),   # non-causal
+    (1, 384, 2, 1, 64, True, 0, 0.0, None),     # falls back to 128
 ]
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("case", SHAPE_SWEEP)
 def test_flash_attention_matches_oracle(case, dtype):
-    B, S, H, K, d, causal, window, softcap = case
+    B, S, H, K, d, causal, window, softcap, block = case
     q, k, v = _qkv(B, S, S, H, K, d, dtype)
     scale = 1.0 / d  # muP 1/d attention folded into the kernel scale
     out = ops.attention(
         q, k, v, scale=scale, causal=causal, window=window, softcap=softcap,
-        block_q=64, block_k=64, impl="interpret",
+        block_q=block, block_k=block, impl="interpret",
     )
     want = ref.attention_ref(
         q, k, v, scale=scale, causal=causal, window=window, softcap=softcap
@@ -177,6 +184,105 @@ def test_resolved_records_the_impl_that_ran(monkeypatch):
     ops.attention(q, k, v, scale=0.1, block_q=64, block_k=64,
                   impl="interpret")
     assert ops.RESOLVED["attention"] == "interpret"
+
+
+@pytest.mark.parametrize("S,window,tiles", [
+    (2048, 0, (512, 512)),     # the SmolLM-360M training cell
+    (512, 0, (512, 512)),      # the sweep engine's proxy
+    (384, 0, (128, 128)),      # 3 x 128: no wider power of two divides it
+    (256, 0, (256, 256)),
+    (64, 0, (64, 64)),         # shorter than a tile: one tile
+    (100, 0, (100, 100)),      # untileable at 128: as before
+    (200, 0, (128, 128)),      # untileable: the caller refuses it
+    (1024, 192, (512, 256)),   # bk holds the window and no more
+    (1024, 64, (512, 128)),
+    (2048, 4096, (512, 512)),
+])
+def test_tile_rule(S, window, tiles):
+    from repro.kernels import flash_attention as fa
+
+    assert fa.choose_tiles(S, S, window=window) == tiles
+    # explicit blocks win, clipped to the sequence
+    assert fa.choose_tiles(S, S, window=window, block_q=64,
+                           block_k=1 << 20) == (min(64, S), S)
+
+
+def test_tile_plan_counts_computed_pairs():
+    from repro.kernels import flash_attention as fa
+
+    # causal 4 x 4: the 10 pairs on or below the diagonal
+    assert fa.tile_plan(2048, 2048, 512, 512, causal=True, window=0) == (
+        10, 16)
+    assert fa.tile_plan(2048, 2048, 512, 512, causal=False, window=0) == (
+        16, 16)
+    # window 192 over 512 x 256: each q tile sees the k tiles of its own
+    # rows and the one before
+    assert fa.tile_plan(1024, 1024, 512, 256, causal=True, window=192) == (
+        5, 8)
+
+
+def test_attention_records_its_tile_plan(monkeypatch):
+    monkeypatch.delenv("REPRO_KERNELS", raising=False)
+    q, k, v = _qkv(1, 1024, 1024, 2, 1, 32, jnp.float32)
+    ops.attention(q, k, v, scale=0.1, causal=True, impl="interpret")
+    assert ops.RESOLVED["attention_tiles"] == "512x512 computed 3/4"
+    ops.attention(q, k, v, scale=0.1, causal=True, impl="ref")
+    assert "attention_tiles" not in ops.RESOLVED   # no kernel ran
+
+
+@pytest.mark.parametrize("window", [0, 192, 700])
+@pytest.mark.parametrize("bq,bk", [(512, 512), (512, 256), (256, 512),
+                                   (128, 128)])
+def test_invisible_tiles_fetch_nothing(bq, bk, window):
+    """The dk/dv kernel's clamped q index maps: every step that computes
+    fetches its own block, and the steps that compute nothing repeat a
+    block that an adjacent step holds, so a k tile's sweep over q makes one
+    copy per visible block."""
+    from repro.kernels import flash_attention as fa
+
+    S = 2048
+    nq, nk = S // bq, S // bk
+    kw = dict(bq=bq, bk=bk, causal=True, window=window)
+    for ki in range(nk):
+        blocks = [int(fa._q_block(ki, qi, nq=nq, **kw)) for qi in range(nq)]
+        seen = [qi for qi in range(nq) if bool(fa._block_visible(
+            qi * bq, ki * bk, bq, bk, True, window))]
+        assert all(blocks[qi] == qi for qi in seen)
+        assert sorted(set(blocks)) == seen
+        assert sum(a != b for a, b in zip(blocks, blocks[1:])) == (
+            len(seen) - 1)
+
+
+def test_clamped_q_maps_change_no_bits(monkeypatch):
+    """The dk/dv kernel fetches a clamped q block where it computes
+    nothing: unclamping its index maps changes neither the output nor the
+    gradients."""
+    from repro.kernels import flash_attention as fa
+
+    q, k, v = _qkv(1, 512, 512, 2, 1, 64, jnp.float32, seed=3)
+
+    def run():
+        jax.clear_caches()
+        fa._flash_fn.cache_clear()
+
+        def f(q, k, v):
+            o = fa.flash_attention(q, k, v, scale=0.125, causal=True,
+                                   window=300, block_q=128, block_k=128,
+                                   interpret=True)
+            return jnp.sum(o * o), o
+
+        (_, o), g = jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(
+            q, k, v)
+        return [np.asarray(x) for x in (o, *g)]
+
+    clamped = run()
+    monkeypatch.setattr(fa, "_q_block", lambda ki, qi, **kw: qi)
+    unclamped = run()
+    monkeypatch.undo()
+    fa._flash_fn.cache_clear()
+    jax.clear_caches()
+    for a, b in zip(clamped, unclamped):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_cross_entropy_explicit_impl_never_silently_falls_back():

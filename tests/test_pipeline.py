@@ -31,6 +31,40 @@ class TestDeterminism:
             np.testing.assert_array_equal(a, b)
 
 
+class TestUnigramDraw:
+    @pytest.mark.parametrize("vocab", [128, 4096, 49152])
+    def test_draw_is_numpy_choice(self, vocab):
+        """The cached-table draw takes the same uniforms as
+        ``RandomState.choice(p=unigram)`` and gives the same tokens."""
+        p = make_pipeline(vocab, 16, 8, seed=5)
+        a, b = np.random.RandomState(11), np.random.RandomState(11)
+        for n in (1, 8, 1000):
+            np.testing.assert_array_equal(
+                p._unigram_draw(a, n), b.choice(vocab, size=n, p=p.unigram))
+        assert a.random_sample() == b.random_sample()   # same stream left
+
+    @pytest.mark.parametrize("seed,step", [(0, 0), (3, 17), (2**31 + 5, 2)])
+    def test_batch_is_the_choice_built_stream(self, seed, step):
+        """A batch is the stream drawn with ``RandomState.choice``, token
+        for token (the pipeline's data did not change with the cached
+        table)."""
+        cfg = DataConfig(1000, 48, 6, seed=seed)
+        p = SyntheticLM(cfg)
+        rng = np.random.RandomState((seed * 1_000_003 + step) % (2**31 - 1))
+        B, S, V = cfg.global_batch, cfg.seq_len, cfg.vocab_size
+        want = np.empty((B, S + 1), np.int32)
+        want[:, 0] = rng.choice(V, size=B, p=p.unigram)
+        for t in range(S):
+            use_markov = rng.random_sample(B) < cfg.markov_p
+            succ = p.successors[want[:, t],
+                                rng.randint(0, cfg.markov_k, size=B)]
+            indep = rng.choice(V, size=B, p=p.unigram)
+            want[:, t + 1] = np.where(use_markov, succ, indep)
+        got = p.batch(step)
+        np.testing.assert_array_equal(got["tokens"], want[:, :-1])
+        np.testing.assert_array_equal(got["labels"], want[:, 1:])
+
+
 class TestHostSharding:
     @settings(max_examples=10, deadline=None)
     @given(hosts=st.sampled_from([1, 2, 4, 8]), step=st.integers(0, 100))

@@ -63,8 +63,11 @@ def _assert_kernel(hlo: str, n: int = 1):
     assert hlo.count("tpu_custom_call") >= n, "no Pallas kernel in the HLO"
 
 
-# (name, B, S, H, K, d): mup-gpt training, smollm-135m prefill widths
-ATTN = [("mup-gpt", 8, 512, 16, 16, 64), ("smollm-135m", 2, 512, 9, 3, 64)]
+# (name, B, S, H, K, d): mup-gpt training, smollm-135m prefill widths, the
+# SmolLM-360M training cell's shapes, and the widest heads in use (gemma2's
+# 256); the tiles come from the shape rule, 512 x 512 at every width
+ATTN = [("mup-gpt", 8, 512, 16, 16, 64), ("smollm-135m", 2, 512, 9, 3, 64),
+        ("smollm-360m", 8, 2048, 15, 5, 64), ("gemma2-2b", 1, 1024, 8, 4, 256)]
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -79,6 +82,7 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, name, B, S, H, K, d,
     q = ((B, S, H, d), dtype)
     kv = ((B, S, K, d), dtype)
     _assert_kernel(_compile(loss, one_chip, q, kv, kv))
+    assert ops.RESOLVED["attention_tiles"].startswith("512x512 ")
     # forward kernel + dq and dk/dv backward kernels
     _assert_kernel(
         _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, q, kv, kv), 3
