@@ -1,16 +1,22 @@
-"""Cells, configurations, traffic mixes and per-layer metrics, found by name.
+"""Cells, configurations, traffic mixes, model families and per-layer
+metrics, found by name.
 
 ``BENCHMARK.json`` at the root of the checkout names every cell; each cell
 names a configuration (``bench/configs/<name>.json``) and a traffic mix
-(``bench/traffic/<name>.json``).  Each per-layer metric is a reader in
-``bench/metrics/<name>.py``.  Adding a cell, a configuration or a metric
-adds files and entries and edits none.
+(``bench/traffic/<name>.json``).  The configuration names its model family,
+``bench/families/<family>.py``; the traffic file names the cell's
+``(data, model)`` mesh over exactly the cell's chips and, for training,
+whether the training state is sharded over it (``fsdp``).  Each per-layer metric is a reader in
+``bench/metrics/<name>.py``.  Adding a cell, a configuration, a family or a
+metric adds files and entries and edits none.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
+import sys
 from pathlib import Path
 from typing import Callable, Optional
 
@@ -52,7 +58,12 @@ def resolve(name: str, root: Path = ROOT) -> Cell:
     w = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
     config = load_json(root / configs[w["config"]]["file"])
-    traffic = load_json(BENCH / "traffic" / f"{w['traffic']}.json")
+    traffic = load_json(root / "bench" / "traffic" / f"{w['traffic']}.json")
+    data, model = traffic["mesh"]
+    if data * model != w["chips"]:
+        raise ValueError(
+            f"cell {name!r}: traffic {w['traffic']!r} asks for a "
+            f"{data} x {model} mesh, but the cell has {w['chips']} chip(s)")
     e2e = [m for m in bench["end_to_end"] if _applies(m, name)]
     e2e_names = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"]
@@ -61,15 +72,37 @@ def resolve(name: str, root: Path = ROOT) -> Cell:
                 end_to_end=e2e, per_layer=per_layer)
 
 
+@functools.cache
+def _module(path: Path):
+    """The Python file at ``path``, imported once per process (and listed
+    in ``sys.modules``, where a dataclass looks its module up)."""
+    name = "bench_" + "_".join(path.parts[-2:]).replace(".", "_").replace(
+        "-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def metric_reader(name: str) -> Callable:
     """``read(run) -> float | None`` from ``bench/metrics/<name>.py``."""
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(BENCH / "metrics" / f"{name}.py").read
+
+
+def family(cell: Cell):
+    """The model family the cell's configuration names
+    (``bench/families/<family>.py``): the program's configuration, the plain
+    reference and the FLOP counts of that architecture."""
+    return _module(BENCH / "families" / f"{cell.config['family']}.py")
+
+
+def mesh(cell: Cell, devices):
+    """The cell's ``(data, model)`` mesh over exactly its own chips."""
+    from repro.launch.mesh import make_mesh_shape
+
+    return make_mesh_shape(tuple(cell.traffic["mesh"]),
+                           devices=list(devices)[:cell.chips])
 
 
 def limits(name: str) -> dict:

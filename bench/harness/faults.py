@@ -6,6 +6,10 @@ the tests read them at test size.
 - ``frozen_state``: the training step returns its state unchanged;
 - ``half_batch``: the training step sees half of the batch, and the loss is
   the mean over that half;
+- ``no_exchange``: the gradient is not reduced between the chips of the
+  ``data`` axis: each chip updates its own shard of a weight with the
+  gradient of its own rows alone (a weight split over no chip takes the
+  first chip's rows);
 - ``altered_token``: the serving engine's decode emits the token after the
   one it sampled.
 """
@@ -55,6 +59,51 @@ def _half_batch():
     return _wrap_train_steps(wrap)
 
 
+def _no_exchange():
+    import jax
+    import jax.numpy as jnp
+
+    from repro.launch import steps
+
+    grads_of, shardings_of, placed = (steps.accumulate_gradients,
+                                      steps.param_shardings, {})
+
+    def param_shardings(*a, **k):
+        placed["params"] = out = shardings_of(*a, **k)
+        return out
+
+    def own_chunk(x, sharding, chip, chips):
+        """True where ``x``'s elements live on data-axis chip ``chip``."""
+        for dim, axes in enumerate(sharding.spec):
+            axes = axes if isinstance(axes, tuple) else (axes,)
+            if axes[:1] == ("data",):
+                shape = [1] * x.ndim
+                shape[dim] = x.shape[dim]
+                on = jnp.arange(x.shape[dim]) // (x.shape[dim] // chips)
+                return (on == chip).reshape(shape)
+        return jnp.asarray(chip == 0)
+
+    def local_gradients(loss_fn, params, batch, num_microbatches):
+        p_sh = placed["params"]
+        chips = jax.tree_util.tree_leaves(p_sh)[0].mesh.shape["data"]
+        rows = batch["tokens"].shape[0] // chips
+        losses, out = [], None
+        for c in range(chips):
+            part = {k: v[c * rows:(c + 1) * rows] for k, v in batch.items()}
+            loss, g = grads_of(loss_fn, params, part, num_microbatches)
+            g = jax.tree_util.tree_map(
+                lambda x, sh, c=c: jnp.where(own_chunk(x, sh, c, chips), x, 0),
+                g, p_sh)
+            losses.append(loss)
+            out = g if out is None else jax.tree_util.tree_map(jnp.add, out, g)
+        return jnp.mean(jnp.stack(losses)), out
+
+    steps.param_shardings = param_shardings
+    steps.accumulate_gradients = local_gradients
+    return [(steps, "param_shardings", shardings_of),
+            (steps, "accumulate_gradients", grads_of)]
+
+
 def _altered_token():
     from repro.serving import sampling
 
@@ -68,7 +117,7 @@ def _altered_token():
 
 
 FAULTS = {"frozen_state": _frozen_state, "half_batch": _half_batch,
-          "altered_token": _altered_token}
+          "no_exchange": _no_exchange, "altered_token": _altered_token}
 
 
 @contextlib.contextmanager

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from harness import program, reference as ref, stats, traffic as traffic_lib
+from harness import cells, reference as ref, stats, traffic as traffic_lib
 from harness.record import Check, Run, Timer
 
 
@@ -36,7 +36,8 @@ def _engine(cell, seed):
     from repro.serving.engine import DynamicEngine, EngineConfig
 
     tr = cell.traffic
-    cfg = program.model_config(cell.config, kv_dtype=tr.get("kv_dtype", ""))
+    cfg = cells.family(cell).program_config(cell.config,
+                                            kv_dtype=tr.get("kv_dtype", ""))
     model = build_model(cfg)
     key = jax.random.PRNGKey(traffic_lib.key_bits(seed))
     dtype = ACT_DTYPES[cfg.dtype]
@@ -77,18 +78,17 @@ def _warm(engine, params, reqs):
     engine.obs = None
 
 
-def knee(cell, seed, seconds, rates):
+def knee(cell, seed, seconds, rates, devices):
     """The knee sweep: one engine, the run's requests replayed at each
     arrival rate (the same gaps, scaled); one latency summary per rate."""
     import jax
 
     from repro.distributed.sharding import make_rules, shardings
-    from repro.launch.mesh import make_host_mesh
 
     tr = cell.traffic
     cfg, model, params, engine = _engine(cell, seed)
     n = traffic_lib.n_requests(tr, seconds)
-    mesh = make_host_mesh()
+    mesh = cells.mesh(cell, devices)
     rules = make_rules(mesh, cfg=cfg, fsdp=False, kind="decode")
     out = []
     with shardings(mesh, rules):
@@ -106,19 +106,19 @@ def knee(cell, seed, seconds, rates):
     return out
 
 
-def serve_window(cell, seed, seconds, timer: Timer, tracer, trace: bool):
+def serve_window(cell, seed, seconds, timer: Timer, tracer, trace: bool,
+                 devices):
     """Set-up and window of one serving run; returns what was served."""
     import jax
 
     from repro.distributed.sharding import make_rules, shardings
-    from repro.launch.mesh import make_host_mesh
     from repro.obs import MetricsRegistry, ServeObs, Tracer
 
     tr = cell.traffic
     cfg, model, params, engine = _engine(cell, seed)
     n = traffic_lib.n_requests(tr, seconds)
     reqs = traffic_lib.serve_requests(tr, seed, n, cfg.vocab_size)
-    mesh = make_host_mesh()
+    mesh = cells.mesh(cell, devices)
     rules = make_rules(mesh, cfg=cfg, fsdp=False, kind="decode")
     with shardings(mesh, rules):
         _warm(engine, params, reqs)
@@ -148,9 +148,10 @@ def serve_window(cell, seed, seconds, timer: Timer, tracer, trace: bool):
 
 
 def run(cell, seed: int, seconds: float, trace: bool, timer: Timer,
-        tracer, control=None) -> Run:
+        tracer, devices, control=None) -> Run:
     tr = cell.traffic
-    served, peak = serve_window(cell, seed, seconds, timer, tracer, trace)
+    served, peak = serve_window(cell, seed, seconds, timer, tracer, trace,
+                                devices)
     lat = stats.latency(served["token_times"], served["arrivals"],
                         served["lengths"])
     gen = int(tr["gen_len"])
@@ -195,21 +196,22 @@ def reference_gaps(cell, seed: int, served: dict, idx: np.ndarray,
     import jax.numpy as jnp
 
     tr = cell.traffic
-    s = ref.Spec.from_config(cell.config)
+    fam = cells.family(cell)
+    s = fam.Spec.from_config(cell.config)
     m = cell.config["mup"]
     hp = ref.HP(sigma=m["sigma"], alpha_output=m["alpha_output"],
                 alpha_attn=m["alpha_attn"], alpha_embed=m["alpha_embed"])
     key = jax.random.PRNGKey(traffic_lib.key_bits(seed))
     dtype = jnp.dtype(cell.config["dtype"])
-    theta = jax.jit(lambda k: ref.init(k, s, hp.sigma, dtype))(key)
+    theta = jax.jit(lambda k: fam.init(k, s, hp.sigma, dtype))(key)
     G = int(tr["gen_len"])
     width = int(tr["max_prompt_len"]) + G
 
     def logits(theta, toks, start):
-        h = ref.hidden(theta, toks, s, hp)
+        h = fam.hidden(theta, toks, s, hp)
         pos = start[:, None] + jnp.arange(G)[None]         # (b, G)
         hg = jnp.take_along_axis(h, pos[..., None], axis=1)
-        return ref.readout(theta, hg, s, hp)                # (b, G, V)
+        return fam.readout(theta, hg, s, hp)                # (b, G, V)
 
     @jax.jit
     def gaps(theta, toks, start, picked):

@@ -14,7 +14,7 @@ import time
 
 import numpy as np
 
-from harness import program, reference as ref, traffic as traffic_lib
+from harness import cells, reference as ref, traffic as traffic_lib
 from harness.record import Run, Timer
 from harness.run_train import check_numbers
 
@@ -51,8 +51,9 @@ def _build(cell, seed):
     from repro.optim.optimizer import Optimizer
 
     tr = cell.traffic
-    cfg = program.model_config(cell.config, use_pallas=tr["use_pallas"],
-                               remat=tr["remat"]).replace(dtype="float32")
+    cfg = cells.family(cell).program_config(
+        cell.config, use_pallas=tr["use_pallas"],
+        remat=tr["remat"]).replace(dtype="float32")
     model = build_model(cfg)
     p13n = model.p13n
     cands = candidates(cell, seed)
@@ -82,17 +83,21 @@ def _build(cell, seed):
 
 
 def run(cell, seed: int, seconds: float, trace: bool, timer: Timer,
-        tracer, control=None) -> Run:
+        tracer, devices, control=None) -> Run:
     import jax
     import jax.numpy as jnp
 
     tr = cell.traffic
+    if tuple(tr["mesh"]) != (1, 1):
+        raise ValueError("the sweep engine runs its candidates on one chip; "
+                         f"traffic asks for mesh {tr['mesh']}")
     n_check = int(tr["checked_steps"])
     if control == "reference":
         timer.window_start()
         timer.window_stop()
         r = Run(kind="sweep", cell=cell, window_s=0.0, attempted=0, failed=0)
-        r.checks = check(cell, seed, None, None, None, n=n_check, low=True)
+        r.checks = check(cell, seed, None, None, None, devices, n=n_check,
+                         low=True)
         return r
     o = _build(cell, seed)
     step, batches, hp = o["step"], o["batches"], o["hp_stack"]
@@ -139,11 +144,11 @@ def run(cell, seed: int, seconds: float, trace: bool, timer: Timer,
             step_stamps=stamps, tokens=tokens)
     r.e2e["train_tokens_per_s"] = tokens / timer.window_s
     r.extra["alive"] = alive
-    r.checks = check(cell, seed, np.stack(losses, 1), g0, d3)
+    r.checks = check(cell, seed, np.stack(losses, 1), g0, d3, devices)
     return r
 
 
-def check(cell, seed, losses, g0, d3, n=None, low=False):
+def check(cell, seed, losses, g0, d3, devices, n=None, low=False):
     """Compare a sample of candidates, drawn from the seed among those
     whose checked steps stayed finite, with the reference; each number is
     the worst over the sampled candidates.  With ``low`` the reference in
@@ -171,11 +176,11 @@ def check(cell, seed, losses, g0, d3, n=None, low=False):
         hp = ref.HP(lr=h.lr, sigma=h.sigma, alpha_output=h.alpha_output,
                     alpha_attn=h.alpha_attn, alpha_embed=h.alpha_embed)
         kw = dict(hp=hp, candidate=int(i), clip=False, total_steps=0)
-        want = reference_steps(cell, seed, fed, **kw)
+        want = reference_steps(cell, seed, fed, devices, **kw)
         if low:
             with ref.operands(jnp.dtype(tr["control"]["operands"])):
                 got_l, got_g, got_d = reference_steps(cell, seed, fed,
-                                                           **kw)
+                                                      devices, **kw)
         else:
             got_l, got_g, got_d = list(losses[i]), g0[i], d3[i]
         got = check_numbers(cell, got_l, got_g, got_d, want,

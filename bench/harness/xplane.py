@@ -3,14 +3,16 @@
 The window is the bench's own ``bench.window`` span on the host.  Device
 work is the ``XLA Ops`` line of each ``/device:TPU:<n>`` plane: ops nest
 (a ``while`` spans its body), so busy time is the union of their intervals
-and an op's own time excludes the ops nested inside it.  Idle gaps are the
-stretches of the window in which no op ran, each labelled by the innermost
-``bench.*`` host span around it.
+and an op's own time excludes the ops nested inside it; an op is named by
+the head of its HLO text, and a fusion also by the computation it calls.
+Idle gaps are the stretches of the window in which no op ran, each labelled
+by the innermost ``bench.*`` host span around it.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
+import re
 from pathlib import Path
 
 WINDOW = "bench.window"
@@ -26,6 +28,8 @@ class Summary:
     op_count: dict
     idle_by_span: dict             # host span label -> idle seconds
     gaps: list                     # (start_s, end_s, label), window-relative
+    op_calls: dict = dataclasses.field(default_factory=dict)
+                                   # op name -> computation it calls
 
     def kernel_s(self, match) -> float | None:
         """Own seconds of the ops whose name ``match`` accepts; None when
@@ -47,6 +51,13 @@ def op_name(event_name: str) -> str:
     """'%fusion.12 = bf16[...] fusion(...)' -> 'fusion.12'."""
     head = event_name.split(" = ", 1)[0]
     return head.lstrip("%")
+
+
+def op_calls(event_name: str) -> str:
+    """'%fusion.9 = f32[8] fusion(%p), kind=kCustom, calls=%all-reduce-scatter'
+    -> 'all-reduce-scatter'; '' for an op that calls no computation."""
+    m = re.search(r"\bcalls=%?([^,\s]+)", event_name)
+    return m.group(1) if m else ""
 
 
 def _merge(iv):
@@ -101,6 +112,7 @@ def summarize(path: Path) -> Summary:
     w0, w1 = window
     busy, own, count, planes = [], collections.Counter(), \
         collections.Counter(), 0
+    calls = {}
     merged_all = []
     for plane in pd.planes:
         if not plane.name.startswith("/device:TPU:"):
@@ -112,7 +124,10 @@ def summarize(path: Path) -> Summary:
             for e in line.events:
                 a, b = max(e.start_ns, w0), min(e.start_ns + e.duration_ns, w1)
                 if b > a:
-                    evs.append((op_name(e.name), a, b))
+                    n = op_name(e.name)
+                    evs.append((n, a, b))
+                    if n not in calls:
+                        calls[n] = op_calls(e.name)
         if not evs:
             continue
         planes += 1
@@ -143,4 +158,5 @@ def summarize(path: Path) -> Summary:
         op_count=dict(count),
         idle_by_span=dict(idle),
         gaps=gaps,
+        op_calls={n: c for n, c in calls.items() if c},
     )

@@ -97,7 +97,8 @@ def main(argv=None) -> int:
         from harness import run_serve
 
         rates = [float(r) for r in args.rates.split(",")]
-        for lat in run_serve.knee(cell, args.seed, args.seconds, rates):
+        for lat in run_serve.knee(cell, args.seed, args.seconds, rates,
+                                  devices[:cell.chips]):
             print(json.dumps(lat), flush=True)
         return 0
     execute(cell, args, T_PROCESS, devices[:cell.chips])
@@ -119,7 +120,7 @@ def execute(cell, args, t_process, devices):
     ops.RESOLVED.clear()
     with faults.planted(getattr(args, "fault", None)):
         run = mod.run(cell, args.seed, args.seconds, bool(args.trace), timer,
-                      tracer, control=args.control)
+                      tracer, devices, control=args.control)
     run.compile_events = dict(compile_cache.EVENTS)
     run.extra["window_compiles"] = timer.window_compiles
     dev = devices[0]
@@ -157,8 +158,8 @@ def execute(cell, args, t_process, devices):
 
 def _sizes(cell) -> str:
     c, t = cell.config, cell.traffic
-    keys = ("batch", "seq_len", "candidates", "n_slots", "max_prompt_len",
-            "gen_len", "n_pages", "prefill_chunk", "remat")
+    keys = ("mesh", "fsdp", "batch", "seq_len", "candidates", "n_slots",
+            "max_prompt_len", "gen_len", "n_pages", "prefill_chunk", "remat")
     return (f"sizes: {c['name']} L{c['num_hidden_layers']} "
             f"d{c['hidden_size']} {c['dtype']}; "
             + ", ".join(f"{k} {t[k]}" for k in keys if k in t))

@@ -32,6 +32,7 @@ def resolve(workload: str):
     cell.name = workload
     cell.config = cells.load_json(cells.BENCH / "configs" / f"{config}.json")
     cell.traffic = cells.load_json(cells.BENCH / "traffic" / f"{traffic}.json")
+    cell.chips = cell.traffic["mesh"][0] * cell.traffic["mesh"][1]
     return cell
 
 
@@ -70,4 +71,5 @@ def run_tiny(cell, seed: int = 2**31 + 77, seconds: float = 0.5,
 
     args = types.SimpleNamespace(seed=seed, seconds=seconds, trace=trace,
                                  control=control)
-    return run_py.execute(cell, args, time.perf_counter(), jax.devices())
+    return run_py.execute(cell, args, time.perf_counter(),
+                          jax.devices()[:cell.chips])

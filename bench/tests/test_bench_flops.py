@@ -8,6 +8,7 @@ import pytest
 from harness import cells, flops
 
 METRICS = Path(__file__).resolve().parents[1] / "metrics"
+LLAMA = cells.family(cells.resolve("train.smollm-360m.s2048"))
 
 
 def _metric(name):
@@ -26,29 +27,29 @@ SMALL = {"hidden_size": 8, "intermediate_size": 16, "num_hidden_layers": 2,
 def test_matmul_params_by_hand():
     # per layer: q 8x8, o 8x8, k 8x4, v 8x4, gate/up/down 3 x 8x16
     per_layer = 64 + 64 + 32 + 32 + 3 * 128
-    assert flops.matmul_params(SMALL) == 2 * per_layer + 80
+    assert LLAMA.matmul_params(SMALL) == 2 * per_layer + 80
 
 
 def test_matmul_params_smollm_360m():
     c = cells.load_json(cells.BENCH / "configs" / "smollm-360m.json")
     # 32 x (960*960*2 + 960*320*2 + 3*960*2560) + 960*49152 (tied readout)
-    assert flops.matmul_params(c) == 361_758_720
+    assert LLAMA.matmul_params(c) == 361_758_720
 
 
 def test_train_flops_by_hand():
     # 3 x (S x 2N + attention): positions 0..3 attend 1..4 keys, 4*q_dim*L
     # FLOPs per key: 4 * 8 * 2 = 64
-    N = flops.matmul_params(SMALL)
+    N = LLAMA.matmul_params(SMALL)
     want = 3 * (4 * 2 * N + 64 * (1 + 2 + 3 + 4))
-    assert flops.train_flops_per_sequence(SMALL, 4) == pytest.approx(want)
+    assert flops.train_flops_per_sequence(LLAMA, SMALL, 4) == pytest.approx(want)
 
 
 def test_serve_flops_by_hand():
     # prompt 3 tokens (keys 1+2+3), 3 outputs: decode at positions 3, 4
     # (keys 4 + 5)
-    N = flops.matmul_params(SMALL)
+    N = LLAMA.matmul_params(SMALL)
     want = 3 * 2 * N + 64 * 6 + 2 * 2 * N + 64 * 9
-    assert flops.serve_flops(SMALL, [3], [3]) == pytest.approx(want)
+    assert flops.serve_flops(LLAMA, SMALL, [3], [3]) == pytest.approx(want)
 
 
 def test_flash_attention_work_by_hand():
@@ -78,3 +79,47 @@ def test_peaks_table():
     assert p["bf16_flops"] == 197e12 and p["hbm_bytes_per_s"] == 819e9
     with pytest.raises(KeyError):
         cells.peaks("TPU v9 imaginary")
+
+
+def _train_run(chips, summary=None):
+    import types
+
+    c = cells.load_json(cells.BENCH / "configs" / "smollm2-1.7b.json")
+    cell = cells.Cell(name="t", config=c, traffic={"seq_len": 2048,
+                                                   "batch": 32},
+                      chips=chips, end_to_end=[], per_layer=[])
+    return types.SimpleNamespace(kind="train", cell=cell, tokens=10 * 32 * 2048,
+                                 window_s=40.0, attempted=10, trace=summary,
+                                 peaks=cells.peaks("TPU v5 lite"))
+
+
+def test_mfu_is_a_share_of_each_chips_peak():
+    read = cells.metric_reader("mfu.train")
+    one, four = read(_train_run(1)), read(_train_run(4))
+    assert four == pytest.approx(one / 4, rel=1e-12)
+    # 10 steps of 32 x 2048 tokens of SmolLM2-1.7B over 40 s on one chip
+    N = LLAMA.matmul_params(_train_run(1).cell.config)
+    assert N == 24 * (2048 * 2048 * 4 + 3 * 2048 * 8192) + 2048 * 49152
+    want = 320 * flops.train_flops_per_sequence(LLAMA, _train_run(1).cell.config,
+                                                2048)
+    assert one == pytest.approx(100 * want / (40.0 * 197e12))
+
+
+def test_flash_roofline_is_per_chip_whatever_the_planes():
+    from harness import xplane
+
+    def summary(planes):
+        # the same kernels' work split evenly over the planes: each plane's
+        # own time is the whole's over their number, and the summary adds
+        # the planes' own times
+        per_plane = 12.0 / planes
+        return xplane.Summary(
+            window_s=40.0, busy_s=30.0, devices=planes,
+            op_self_s={"_attention_jit.3": per_plane * planes},
+            op_count={"_attention_jit.3": 10 * planes}, idle_by_span={},
+            gaps=[])
+
+    read = cells.metric_reader("flash_attention_roofline.train")
+    one = read(_train_run(1, summary(1)))
+    assert 0 < one < 100
+    assert read(_train_run(4, summary(4))) == pytest.approx(one, rel=1e-12)
